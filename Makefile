@@ -11,10 +11,11 @@ test:
 test-full:
 	PYTHONPATH=src $(PY) -m pytest -q
 
-# Pallas kernel suite alone, in interpret mode (the CI kernels job; on a TPU
-# host run with REPRO_PALLAS_INTERPRET=0 to exercise the compiled kernels)
+# Pallas kernel suite alone (the CI kernels job).  The backend decides:
+# interpret mode on the CPU, compiled on a TPU; tests/test_tpu_compile.py
+# compiles the kernels for a described v5e chip without one
 test-kernels:
-	PYTHONPATH=src REPRO_PALLAS_INTERPRET=1 $(PY) -m pytest -q -m kernels
+	PYTHONPATH=src $(PY) -m pytest -q -m kernels
 
 # serving subsystem alone: continuous-batching engine bit-identity,
 # paged-cache eviction/resume, and streaming-schedule trace audits

@@ -148,7 +148,7 @@ _TRACE_CODE = """
 
 
 def trace_part(emit):
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
                PYTHONPATH=str(Path(__file__).resolve().parents[1] / "src"))
     times = {}

@@ -10,7 +10,7 @@ Self-asserting cells (``--assert-only``, the ``make bench-smoke`` entry)
 check the ISSUE-4 memory claims on the ACTUAL compiled programs:
 
 * HBM traffic of the fused op — fwd AND grad, dense AND GQA — stays LINEAR
-  in ctx+l (``compat.cost_analysis`` bytes accessed; the dense reference's
+  in ctx+l (``cost_analysis()`` bytes accessed; the dense reference's
   score matrix would scale quadratically);
 * no intermediate in the jaxpr has an (l, ctx+l)-shaped score-matrix buffer
   or a GQA-repeated (Sk, Hq) K/V buffer, in forward or backward — via the
@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.analysis import raise_on_errors, rules as arules
-from repro.compat import cost_analysis_dict
 from repro.kernels import ops as kops
 from repro.kernels.ref import terapipe_attention_ref
 
@@ -60,13 +59,13 @@ def _audit_jaxpr(fn, args, *, l, sk, hq, hkv, tag):
     buffer.repeated-kv)."""
     jaxpr = jax.make_jaxpr(fn)(*args)
     findings = arules.check_score_matrix(jaxpr, l=l, sk=sk)
-    findings += arules.check_repeated_kv(jaxpr, sk=sk, hq=hq, hkv=hkv)
+    findings += arules.check_kv_repeat(jaxpr, sk=sk, hq=hq, hkv=hkv)
     raise_on_errors(findings, context=tag)
 
 
 def _bytes_accessed(fn, args):
     compiled = jax.jit(fn).lower(*args).compile()
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     return float(cost.get("bytes accessed", 0.0))
 
 

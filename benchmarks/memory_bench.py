@@ -62,7 +62,7 @@ _CELL_CODE = """
 
 
 def _cell(sched: str, D: int) -> int:
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS=f"--xla_force_host_platform_device_count={K}",
                PYTHONPATH=str(Path(__file__).resolve().parents[1] / "src"))
     code = textwrap.dedent(_CELL_CODE).format(

@@ -89,7 +89,7 @@ _TRACE_CODE = """
 
 
 def _trace_lower_s(sched: str, V: int) -> float:
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
                PYTHONPATH=str(Path(__file__).resolve().parents[1] / "src"))
     code = textwrap.dedent(_TRACE_CODE).replace("{sched}", sched) \

@@ -16,6 +16,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
 if "XLA_FLAGS" not in os.environ:
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    os.environ["JAX_PLATFORMS"] = "cpu"    # host devices; never opens a chip
 
 import jax
 import jax.numpy as jnp

@@ -141,7 +141,7 @@ def audit_cell(cell: Cell, *, compile_donation: bool = False,
                                        expect_rev=True)
     if cell.use_kernel:
         findings += rules.check_score_matrix(jaxpr, l=geom["l"], sk=S)
-        findings += rules.check_repeated_kv(jaxpr, sk=S, hq=geom["hq"],
+        findings += rules.check_kv_repeat(jaxpr, sk=S, hq=geom["hq"],
                                             hkv=geom["hkv"])
     findings += rules.check_carry_stability(jaxpr)
     findings += rules.check_dtype_upcasts(jaxpr)

@@ -209,7 +209,7 @@ def check_score_matrix(jaxpr, *, l: int, sk: int) -> List[Finding]:
 
 
 @register_rule("buffer.repeated-kv", "buffer")
-def check_repeated_kv(jaxpr, *, sk: int, hq: int, hkv: int) -> List[Finding]:
+def check_kv_repeat(jaxpr, *, sk: int, hq: int, hkv: int) -> List[Finding]:
     """No GQA-repeated K/V buffer: with Hkv < Hq no intermediate may carry
     an adjacent ``(Sk, Hq)`` dim pair (K/V materialized at Hq heads)."""
     if hkv == hq:
@@ -387,14 +387,21 @@ def check_dtype_upcasts(jaxpr, *, src: str = "bfloat16",
 VMEM_BUDGET_BYTES = 16 * 2 ** 20
 
 
-def _block_bytes(bm) -> int:
-    n = 1
-    for d in bm.block_shape:
-        try:
-            n *= max(int(d), 1)
-        except (TypeError, ValueError):    # mapped/squeezed dims
-            pass
-    return n * bm.array_shape_dtype.dtype.itemsize
+def _pallas_footprint(eqn):
+    """(kernel name, grid, per-block bytes, scratch bytes) of a
+    ``pallas_call`` equation — the one place that reads Pallas internals.
+
+    A block's bytes come from ``transformed_block_aval``, the ref the kernel
+    body sees (squeezed dims gone); the name is the kernel function's."""
+    gm = eqn.params["grid_mapping"]
+    src = eqn.params["jaxpr"].debug_info.func_src_info or "pallas_call"
+    name = eqn.params.get("name") or src.split(" ")[0]
+    blocks = [math.prod(bm.transformed_block_aval.shape)
+              * bm.transformed_block_aval.dtype.itemsize
+              for bm in gm.block_mappings]
+    scratch = sum(math.prod(a.shape) * getattr(a.dtype, "itemsize", 4)
+                  for a in gm.scratch_avals)
+    return name, tuple(int(g) for g in gm.grid), blocks, scratch
 
 
 @register_rule("vmem.budget", "vmem")
@@ -408,20 +415,11 @@ def check_vmem(jaxpr, *, budget_bytes: int = VMEM_BUDGET_BYTES,
     for site in iter_eqns(jaxpr):
         if site.prim != "pallas_call":
             continue
-        gm = site.eqn.params["grid_mapping"]
-        mult = 2 if (double_buffer and tuple(gm.grid)) else 1
-        block = sum(_block_bytes(bm) for bm in gm.block_mappings) * mult
-        scratch = 0
-        nscr = gm.num_scratch_operands
-        if nscr:
-            for var in site.eqn.params["jaxpr"].invars[-nscr:]:
-                aval = getattr(var.aval, "inner_aval", var.aval)
-                scratch += (math.prod(aval.shape)
-                            * getattr(aval.dtype, "itemsize", 4))
+        name, grid, blocks, scratch = _pallas_footprint(site.eqn)
+        mult = 2 if (double_buffer and grid) else 1
+        block = sum(blocks) * mult
         total = block + scratch
-        name = getattr(site.eqn.params.get("name_and_src_info"), "name",
-                       "pallas_call")
-        data = {"kernel": str(name), "grid": [int(g) for g in gm.grid],
+        data = {"kernel": str(name), "grid": list(grid),
                 "block_bytes": block, "scratch_bytes": scratch,
                 "total_bytes": total, "budget_bytes": budget_bytes}
         if total > budget_bytes:

@@ -9,7 +9,7 @@ exactly one place.
 The recursion contract: an equation parameter contributes a sub-jaxpr when
 it is a ``ClosedJaxpr`` (has ``.jaxpr``), a raw ``Jaxpr`` (has ``.eqns`` —
 shard_map bodies), or a list/tuple of either (``cond``'s ``branches``).
-That matches how jax 0.4.x stores the bodies of ``scan``/``while``/``cond``
+That matches how jax stores the bodies of ``scan``/``while``/``cond``
 /``pjit``/``custom_vjp_call_jaxpr``/``shard_map``/``remat`` and the Pallas
 kernel body in ``pallas_call``'s ``jaxpr`` param.
 """

@@ -1,10 +1,4 @@
-"""Version-portability shims over jax's sharding / shard_map API surface.
-
-The repo targets the modern API (``jax.shard_map``, ``jax.set_mesh``,
-``jax.sharding.AxisType``, ``axis_types=`` on ``jax.make_mesh``), but must
-also run on older installs (>= 0.4.35) where those names either live under
-``jax.experimental`` or do not exist.  All call sites go through this module
-instead of feature-testing jax themselves.
+"""Mesh helpers over jax's sharding API (jax >= 0.9).
 
 Nothing here imports repro modules — safe to import from anywhere.
 """
@@ -14,89 +8,34 @@ import contextlib
 
 import jax
 
-# feature flags (computed once at import)
-HAS_SHARD_MAP = hasattr(jax, "shard_map")                 # public rolled API
-HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-HAS_SET_MESH = hasattr(jax, "set_mesh")
-HAS_ABSTRACT_MESH = hasattr(jax.sharding, "get_abstract_mesh")
-
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with every axis Auto; drops ``axis_types`` on jax
-    versions that predate explicit axis types (their meshes are Auto-only)."""
-    if HAS_AXIS_TYPE:
-        return jax.make_mesh(
-            axis_shapes, axis_names, devices=devices,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
-    return jax.make_mesh(axis_shapes, axis_names, devices=devices)
+    """``jax.make_mesh`` with every axis Auto (jax defaults to Explicit):
+    the executor's shard_maps and ``with_sharding_constraint`` calls rely
+    on GSPMD propagation outside them."""
+    return jax.make_mesh(
+        axis_shapes, axis_names, devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def use_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    New jax: ``jax.set_mesh``.  Old jax: the ``Mesh`` context manager (which
-    sets the thread-local physical mesh that ``with_sharding_constraint`` with
-    bare PartitionSpecs and ``shard_map(mesh=None)`` resolve against)."""
+    """Context manager installing ``mesh`` as the ambient mesh
+    (``jax.set_mesh``); a no-op for ``None``."""
     if mesh is None:
         return contextlib.nullcontext()
-    if HAS_SET_MESH:
-        return jax.set_mesh(mesh)
-    return mesh          # jax.sharding.Mesh is itself a context manager
-
-
-def shard_map(f, *, mesh=None, in_specs, out_specs, check_vma=True):
-    """``jax.shard_map`` or the ``jax.experimental`` fallback.
-
-    ``check_vma`` maps onto the old API's ``check_rep``.  With ``mesh=None``
-    the old fallback resolves the ambient mesh installed by :func:`use_mesh`.
-    """
-    if HAS_SHARD_MAP:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    if mesh is None:
-        mesh = current_mesh()
-        assert mesh is not None, \
-            "shard_map(mesh=None) needs an ambient mesh (compat.use_mesh)"
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
+    return jax.set_mesh(mesh)
 
 
 def current_mesh():
-    """The ambient mesh installed by :func:`use_mesh`, or None.  Never raises.
-
-    Checks the abstract mesh (``jax.set_mesh``) when available, then — on any
-    version where use_mesh fell back to the ``Mesh`` context manager — the
-    thread-local physical mesh.  The second check must not be gated on
-    HAS_ABSTRACT_MESH alone: mid-range jax has get_abstract_mesh but no
-    set_mesh, so the abstract mesh stays empty there."""
-    if HAS_ABSTRACT_MESH:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and not m.empty:
-            return m
-        if HAS_SET_MESH:
-            return None
-    from jax._src.mesh import thread_resources
-    m = thread_resources.env.physical_mesh
-    return None if m.empty else m
-
-
-def cost_analysis_dict(compiled):
-    """``compiled.cost_analysis()`` as a flat dict (older jax wraps the
-    per-device dict in a one-element list)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
+    """The ambient mesh installed by :func:`use_mesh`, or None."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m is None or m.empty else m
 
 
 def auto_axis_names(mesh):
-    """Names of mesh axes usable for *automatic* sharding right now, or None
-    when that cannot be determined (old jax cannot see whether tracing is
-    inside a shard_map, where every axis is Manual)."""
+    """Names of mesh axes usable for *automatic* sharding right now (inside
+    a shard_map every axis is Manual)."""
     if mesh is None:
-        return None
-    if not HAS_AXIS_TYPE:
         return None
     types = dict(zip(mesh.axis_names, mesh.axis_types))
     return tuple(a for a, t in types.items()

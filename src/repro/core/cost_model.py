@@ -47,6 +47,20 @@ TPU_V5E = HardwareSpec("tpu-v5e", 197e12, 819e9, 50e9, 1e-6, 256, 0.55)
 # AWS p3.16xlarge: V100 (125 TF/s fp16), 25 Gbit/s x-node => ~3 GB/s usable
 V100_AWS = HardwareSpec("v100-aws", 125e12, 900e9, 3e9, 20e-6, 256, 0.45)
 
+#: the spec of each accelerator, by ``jax.devices()[0].device_kind``
+HARDWARE_BY_KIND = {"TPU v5 lite": TPU_V5E, "TPU v5e": TPU_V5E}
+
+
+def hardware_for(device_kind: str) -> HardwareSpec:
+    """The HardwareSpec of a device kind; an unknown kind is an error, not
+    a default (planning a CPU run with TPU constants plans nothing)."""
+    try:
+        return HARDWARE_BY_KIND[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no HardwareSpec for device kind {device_kind!r} (known: "
+            f"{sorted(HARDWARE_BY_KIND)})") from None
+
 
 # ---------------------------------------------------------------------------
 # FLOPs accounting (per layer, per token)

@@ -22,9 +22,11 @@ the schedule IR (``core/schedules``): the executor is a single rolled
 
 * the **tick table** ``(tick, rank) -> (work_item, chunk, kind)`` — the
   per-tick unit kind (idle / fwd / fused bwd / split B / split W)
-  dispatches a ``lax.switch``; the chunk index gathers the rank's per-chunk
-  params/caches (shape-stable ``dynamic_index_in_dim`` from the rank-major
-  chunk stacks, so the body traces ONCE regardless of D·M·V);
+  dispatches a ``lax.switch`` (fwd-only tables, which are differentiated
+  through the loop, compute every tick and mask idle ones instead); the
+  chunk index gathers the rank's per-chunk params/caches (shape-stable
+  ``dynamic_index_in_dim`` from the rank-major chunk stacks, so the body
+  traces ONCE regardless of D·M·V);
 * the **comm plan** (``StageAssignment.comm_plan``) — whether the reverse
   cotangent ring fires, the *skew hold* of each ring: wrap-around chunk
   handoffs (global stage ``v·K+K-1 -> (v+1)·K``) ride their ring one hop
@@ -111,7 +113,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map as compat_shard_map
 from repro.core.schedules import (KIND_BWD_INPUT, KIND_BWD_WEIGHT, KIND_FWD, get_schedule, interleave_stacked, schedule_names, uninterleave_stacked)
 from repro.models import Model, build_model
 from repro.models.common import ModelConfig, rms_norm
@@ -138,11 +139,6 @@ class TeraPipeConfig:
     tp_axis: Optional[str] = None    # None => no TP within a stage
     data_axes: Tuple[str, ...] = ("data",)
     cache_dtype: Any = jnp.bfloat16
-    # bubble ticks (stage idle in the fill/drain phases) skip the stage
-    # compute via the unit-kind switch — at runtime an idle device runs the
-    # cheap branch instead of masked garbage compute.  False (debugging,
-    # fwd-only schedules only) computes every tick and masks the merge.
-    skip_bubbles: bool = True
     # Python-unroll the tick loop (one jaxpr copy per tick) instead of the
     # rolled lax.scan executor.  Trace/compile cost grows with the tick
     # count; differential-testing / HLO-inspection escape hatch only.
@@ -341,10 +337,10 @@ class _Plan:
         """Pad the stacked main group to the schedule's row count and (V>1)
         reorder rank-major, constrained straight to the pipe-sharded layout.
 
-        NB: must be jnp.pad, NOT concatenate-with-zeros — XLA mispartitions
-        the concat feeding a shard_map operand on multi-axis meshes
-        (data>1 x pipe, observed on jax 0.4.37: garbage stage params).
-        interleave_stacked is reshape+swapaxes for the same reason."""
+        NB: jnp.pad, not concatenate-with-zeros: XLA once mispartitioned the
+        concat feeding a shard_map operand on multi-axis meshes (data>1 x
+        pipe: garbage stage params).  interleave_stacked is reshape+swapaxes
+        for the same reason."""
         if not (self.n_pad or self.V > 1):
             return stage_params
 
@@ -501,9 +497,9 @@ def _make_pipeline_body(p: _Plan):
                 tree, sub)
 
         def tree_where(pred, a, b):
-            return jax.tree.map(
-                lambda x, y: jnp.where(jnp.reshape(pred, (1,) * x.ndim), x,
-                                       y), a, b)
+            # a cond, not jnp.where: under autodiff a where saves its
+            # predicate broadcast to the full (cache-sized) shape per tick
+            return jax.lax.cond(pred, lambda: a, lambda: b)
 
         def tick(carry, t):
             """One pipeline tick.  ``t`` is traced — the body is shape-
@@ -719,13 +715,14 @@ def _make_pipeline_body(p: _Plan):
                 else:
                     out = jax.lax.switch(branch, (idle_branch, fwd_branch,
                                                   bwd_branch), carry)
-            elif tcfg.skip_bubbles:
-                out = jax.lax.switch(branch, (idle_branch, fwd_branch),
-                                     carry)
             else:
-                # debug: compute every tick, mask the merge (fwd-only)
-                computed = fwd_branch(carry)
-                out = tree_where(branch > 0, computed, carry)
+                # fwd-only schedules are differentiated through this scan,
+                # so every tick computes and the merge is masked: a switch
+                # skipping idle ticks would make the stage params a residual
+                # of the switch, stacked once per tick (T copies of the
+                # stage's weights).  In SPMD lockstep an idle rank waits for
+                # the busy ones anyway, so the tick takes no longer.
+                out = tree_where(branch > 0, fwd_branch(carry), carry)
             # activations ride the forward ring (issued BEFORE the trailing
             # bookkeeping below so the async collective overlaps it);
             # cotangents ride the reverse ring.  Consumers read a ring value
@@ -837,7 +834,7 @@ def _make_loss_from_plan(p: _Plan):
 
     pipeline_body = _make_pipeline_body(p)
     out_specs = P(tcfg.pipe_axis, tcfg.data_axes, None, None)
-    shmap = compat_shard_map(
+    shmap = jax.shard_map(
         lambda sp, x: pipeline_body(sp, x)[0], mesh=mesh,
         in_specs=(p.stage_in_specs, p.x_spec),
         out_specs=out_specs, check_vma=False)
@@ -894,7 +891,7 @@ def make_terapipe_caches_fn(model: Model, specs, mesh: Mesh,
     cache_out_specs = jax.tree.map(
         lambda a: P(*((tcfg.pipe_axis,) + (None,) * (a.ndim - 1))),
         cache_struct)
-    shmap = compat_shard_map(
+    shmap = jax.shard_map(
         lambda sp, x: pipeline_body(sp, x)[1], mesh=mesh,
         in_specs=(p.stage_in_specs, p.x_spec),
         out_specs=cache_out_specs, check_vma=False)
@@ -927,7 +924,7 @@ def _make_explicit_value_and_grad(p: _Plan):
     pipeline_body = _make_pipeline_body(p)
     head_in_specs = (P(None), P(None, None))
     labels_spec = P(tcfg.data_axes, None)
-    shmap = compat_shard_map(
+    shmap = jax.shard_map(
         lambda sp, hp, x, lab: pipeline_body(sp, x, hp, lab), mesh=p.mesh,
         in_specs=(p.stage_in_specs, head_in_specs, p.x_spec, labels_spec),
         out_specs=(P(), p.stage_in_specs, P(None), P(None, None),
@@ -985,15 +982,13 @@ def make_terapipe_value_and_grad(model: Model, specs, mesh: Mesh,
 def make_gpipe_loss(model: Model, specs, mesh: Mesh, *, n_microbatches: int,
                     pipe_axis="pipe", tp_axis=None, data_axes=("data",),
                     seq_len: int, global_batch: int,
-                    cache_dtype: Any = jnp.bfloat16, skip_bubbles: bool = True,
-                    unroll: bool = False):
+                    cache_dtype: Any = jnp.bfloat16, unroll: bool = False):
     """Microbatch-only pipelining (GPipe, the paper's baseline): D micro-
     batches, a single token slice per sequence.  ``cache_dtype`` /
-    ``skip_bubbles`` / ``unroll`` forward into the underlying TeraPipeConfig
-    so the baseline is controllable exactly like the TeraPipe executor."""
+    ``unroll`` forward into the underlying TeraPipeConfig so the baseline
+    is controllable exactly like the TeraPipe executor."""
     tcfg = TeraPipeConfig(n_token_slices=1, n_microbatches=n_microbatches,
                           pipe_axis=pipe_axis, tp_axis=tp_axis,
                           data_axes=tuple(data_axes),
-                          cache_dtype=cache_dtype, skip_bubbles=skip_bubbles,
-                          unroll=unroll)
+                          cache_dtype=cache_dtype, unroll=unroll)
     return make_terapipe_loss(model, specs, mesh, tcfg, seq_len, global_batch)

@@ -8,6 +8,8 @@ pass over the cache with running softmax in VMEM scratch.
 
 Grid (B, Hq, L/blk_kv), KV block innermost (TPU grids are sequential
 minor-to-major so the scratch accumulator persists across the KV sweep).
+Like the flash kernels it runs head-major (``to_heads``), so each block is
+a (rows, hd) tile of one head, as Mosaic's tiling rule requires.
 """
 from __future__ import annotations
 
@@ -19,11 +21,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .terapipe_attention import from_heads, to_heads
+
 DEFAULT_BLOCK_KV = 512
 NEG_INF = float("-inf")
-
-# renamed pltpu.TPUMemorySpace -> pltpu.MemorySpace across jax versions
-_MEMORY_SPACE = getattr(pltpu, "MemorySpace", None) or pltpu.TPUMemorySpace
 
 
 def _decode_kernel(kvlen_ref, q_ref, k_ref, v_ref, o_ref, m_scr, s_scr,
@@ -43,28 +44,29 @@ def _decode_kernel(kvlen_ref, q_ref, k_ref, v_ref, o_ref, m_scr, s_scr,
 
     @pl.when(ikv * blk_kv < kv_len)
     def _compute():
-        q = q_ref[0, 0, 0, :].astype(jnp.float32)              # (hd,)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)              # (blk_kv, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        logits = (k @ q) * scale                               # (blk_kv,)
-        kv_pos = ikv * blk_kv + jax.lax.broadcasted_iota(
-            jnp.int32, (blk_kv,), 0)
-        logits = jnp.where(kv_pos < kv_len, logits, NEG_INF)
-        logits2 = logits[None, :]                              # (1, blk_kv)
+        q = q_ref[...].astype(jnp.float32)                     # (1, hd)
+        k = k_ref[...].astype(jnp.float32)                     # (blk_kv, hd)
+        v = v_ref[...].astype(jnp.float32)
+        logits = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale        # (1, blk_kv)
+        valid = ikv * blk_kv + jax.lax.broadcasted_iota(
+            jnp.int32, (1, blk_kv), 1) < kv_len
+        logits = jnp.where(valid, logits, NEG_INF)
 
         m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(logits2, axis=-1, keepdims=True))
-        p = jnp.exp(logits2 - m_new)
-        p = jnp.where(kv_pos[None, :] < kv_len, p, 0.0)
+        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+        p = jnp.where(valid, jnp.exp(logits - m_new), 0.0)
         alpha = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_new), 0.0)
         s_scr[...] = s_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + p @ v
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         m_scr[...] = m_new
 
     @pl.when(ikv == n_kv - 1)
     def _finalize():
         denom = jnp.maximum(s_scr[...], 1e-30)
-        o_ref[0, 0, 0, :] = (acc_scr[...] / denom)[0].astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("blk_kv", "interpret"))
@@ -79,35 +81,27 @@ def decode_attention_kernel(q, k, v, kv_len, *,
     L, hkv = k.shape[1], k.shape[2]
     rep = hq // hkv
     blk_kv = min(blk_kv, L)
-    L_pad = -L % blk_kv
-    if L_pad:
-        k = jnp.pad(k, ((0, 0), (0, L_pad), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, L_pad), (0, 0), (0, 0)))
-    Lp = L + L_pad
+    kh, vh = to_heads(k, -L % blk_kv), to_heads(v, -L % blk_kv)
     scale = 1.0 / math.sqrt(hd)
     kv_len_arr = jnp.broadcast_to(
         jnp.asarray(kv_len, jnp.int32).reshape(-1), (b,))
 
-    grid = (b, hq, Lp // blk_kv)
+    q_spec = pl.BlockSpec((None, None, 1, hd), lambda bi, hi, ki: (bi, hi, 0, 0))
+    # GQA: the kv-head block index is hq // rep — no repeat in HBM
+    kv_spec = pl.BlockSpec((None, None, blk_kv, hd),
+                           lambda bi, hi, ki: (bi, hi // rep, ki, 0))
     out = pl.pallas_call(
         functools.partial(_decode_kernel, blk_kv=blk_kv, scale=scale),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=_MEMORY_SPACE.SMEM),  # kv_len
-            pl.BlockSpec((1, 1, 1, hd), lambda bi, hi, ki: (bi, 0, hi, 0)),
-            # GQA: the kv-head block index is hq // rep — no repeat in HBM
-            pl.BlockSpec((1, blk_kv, 1, hd),
-                         lambda bi, hi, ki: (bi, ki, hi // rep, 0)),
-            pl.BlockSpec((1, blk_kv, 1, hd),
-                         lambda bi, hi, ki: (bi, ki, hi // rep, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, 1, hd), lambda bi, hi, ki: (bi, 0, hi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, 1, hq, hd), q.dtype),
+        grid=(b, hq, kh.shape[2] // blk_kv),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.MemorySpace.SMEM),  # kv_len
+                  q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hq, 1, hd), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((1, 1), jnp.float32),     # running max
             pltpu.VMEM((1, 1), jnp.float32),     # running denom
             pltpu.VMEM((1, hd), jnp.float32),    # output acc
         ],
         interpret=interpret,
-    )(kv_len_arr, q, k, v)
-    return out
+    )(kv_len_arr, to_heads(q, 0), kh, vh)
+    return from_heads(out, 1)

@@ -1,8 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On this CPU container kernels execute in interpret mode (the kernel body runs
-as plain JAX ops); on TPU set REPRO_PALLAS_INTERPRET=0 to compile for real
-(see EXPERIMENTS.md §Kernels).
+The backend decides how a kernel runs: on a TPU it is compiled by Mosaic;
+on the CPU it runs in interpret mode (the kernel body as plain JAX ops), so
+tests and CPU tools check the same code; any other backend is an error.
 
 ``terapipe_attention`` is fully fused fwd+bwd: the forward saves (O, lse)
 residuals and the backward runs the flash dQ / dK-dV Pallas kernels
@@ -18,7 +18,6 @@ would defeat jit caching and retrace on every call.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -28,7 +27,13 @@ from .terapipe_attention import (DEFAULT_BLOCK_KV, DEFAULT_BLOCK_Q,
                                  terapipe_attention_fwd)
 from .terapipe_attention_bwd import terapipe_attention_bwd
 
-_INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
+
+def _interpret() -> bool:
+    """True on the CPU (interpret mode), False on a TPU (compiled)."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(f"Pallas TPU kernels cannot run on {backend!r}")
+    return backend == "cpu"
 
 
 @functools.lru_cache(maxsize=None)
@@ -76,7 +81,7 @@ def terapipe_attention(q, k, v, *, ctx_len,
     or a traced int32 scalar (the executors' lockstep dynamic-ctx path).
     Differentiable via the fused flash backward kernels.
     """
-    attn = _make_flash_attention(blk_q, blk_kv, _INTERPRET)
+    attn = _make_flash_attention(blk_q, blk_kv, _interpret())
     return attn(q, k, v, jnp.asarray(ctx_len, jnp.int32))
 
 
@@ -86,4 +91,4 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     a scalar, or a per-batch (B,) vector for continuous-batching rounds
     that mix context depths.  GQA resolved inside the kernel's BlockSpec
     index map (no K/V repeat)."""
-    return decode_attention_kernel(q, k, v, kv_len, interpret=_INTERPRET)
+    return decode_attention_kernel(q, k, v, kv_len, interpret=_interpret())

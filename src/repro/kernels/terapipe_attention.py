@@ -10,7 +10,10 @@ whole forward through the dense reference.
 TPU mapping (DESIGN.md §3): grid (B, Hq, n_q_blocks, n_kv_blocks) with the
 KV block index innermost — TPU grids execute sequentially minor-to-major, so
 the running-softmax state (m, s, acc) lives in VMEM scratch and persists
-across the KV sweep of one query block.  Three properties added by ISSUE 4:
+across the KV sweep of one query block.  The wrapper takes (B, L, H, hd)
+and runs the kernel head-major, (B, H, L, hd) (``to_heads``): every block is
+then a (rows, hd) tile of one head, which Mosaic's (8, 128) tiling rule
+accepts; lse is a (B, H, L, 1) column for the same reason.  Also:
 
 * ``ctx`` is a SCALAR-PREFETCH operand (``pltpu.PrefetchScalarGridSpec``):
   it may be a traced int32 — the lockstep pipeline executors run every stage
@@ -24,8 +27,8 @@ across the KV sweep of one query block.  Three properties added by ISSUE 4:
   ragged slice length (the DP planner emits e.g. l=96 slices); the position
   masks make the pad exact.
 
-Validated in interpret mode against kernels.ref (CPU container; TPU is the
-compile target).
+Checked against kernels.ref in interpret mode on CPU; tests/test_tpu_compile.py
+compiles it for TPU v5e.
 """
 from __future__ import annotations
 
@@ -72,9 +75,9 @@ def _fwd_kernel(ctx_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     # ctx + l valid-key limit: pad rows would otherwise attend stale cache)
     @pl.when(ikv * blk_kv < ctx + jnp.minimum((iq + 1) * blk_q, l))
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)          # (blk_q, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)          # (blk_kv, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[...].astype(jnp.float32)                  # (blk_q, hd)
+        k = k_ref[...].astype(jnp.float32)                  # (blk_kv, hd)
+        v = v_ref[...].astype(jnp.float32)
         logits = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale     # (blk_q, blk_kv)
@@ -97,12 +100,31 @@ def _fwd_kernel(ctx_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     @pl.when(ikv == n_kv - 1)
     def _finalize():
         s = jnp.maximum(s_scr[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_scr[...] / s).astype(o_ref.dtype)
-        lse_ref[0, 0, :] = (m_scr[...] + jnp.log(s))[:, 0]
+        o_ref[...] = (acc_scr[...] / s).astype(o_ref.dtype)
+        lse_ref[...] = m_scr[...] + jnp.log(s)
 
 
-def _pad_seq(a, pad):
-    return jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0))) if pad else a
+def to_heads(a, pad):
+    """(B, L, H, hd) -> head-major (B, H, L + pad, hd), the kernels' layout.
+
+    Mosaic tiles the last two dims of every block by (8, 128) or takes them
+    whole, so a block may not hold one head of a (B, L, H, hd) array; head-
+    major makes each block a (rows, hd) tile of one head."""
+    if pad:
+        a = jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    return jnp.swapaxes(a, 1, 2)
+
+
+def from_heads(a, n: int):
+    """Head-major (B, H, Lp, hd) -> (B, n, H, hd): drop the pad rows."""
+    return jnp.swapaxes(a[:, :, :n], 1, 2)
+
+
+def row_spec(blk: int, index_map):
+    """BlockSpec of a per-row f32 statistic (lse, delta) kept as a
+    (B, H, L, 1) column: its (blk, 1) block meets Mosaic's tiling rule and
+    broadcasts against a (blk, blk_kv) tile without a relayout."""
+    return pl.BlockSpec((None, None, blk, 1), index_map)
 
 
 @functools.partial(jax.jit, static_argnames=("blk_q", "blk_kv", "interpret"))
@@ -123,10 +145,10 @@ def terapipe_attention_fwd(q, k, v, ctx, *,
     blk_kv = align_block(blk_kv, sk)
     scale = 1.0 / math.sqrt(hd)
 
-    q = _pad_seq(q, -l % blk_q)
-    k = _pad_seq(k, -sk % blk_kv)
-    v = _pad_seq(v, -sk % blk_kv)
-    lp, skp = q.shape[1], k.shape[1]
+    qh = to_heads(q, -l % blk_q)
+    kh = to_heads(k, -sk % blk_kv)
+    vh = to_heads(v, -sk % blk_kv)
+    lp, skp = qh.shape[2], kh.shape[2]
     ctx_arr = jnp.asarray(ctx, jnp.int32).reshape((1,))
 
     # GQA: kv-head block = q head // rep — no repeat in HBM.  The kv BLOCK
@@ -136,23 +158,18 @@ def terapipe_attention_fwd(q, k, v, ctx, *,
     # O(ctx + l), not O(Sk), even though the grid spans the whole cache.
     def _kv_index(bi, hi, qi, ki, ctx_ref):
         last = (ctx_ref[0] + jnp.minimum((qi + 1) * blk_q, l) - 1) // blk_kv
-        return (bi, jnp.minimum(ki, last), hi // rep, 0)
+        return (bi, hi // rep, jnp.minimum(ki, last), 0)
 
+    def _q_index(bi, hi, qi, ki, *_):
+        return (bi, hi, qi, 0)
+
+    q_spec = pl.BlockSpec((None, None, blk_q, hd), _q_index)
+    kv_spec = pl.BlockSpec((None, None, blk_kv, hd), _kv_index)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b, hq, lp // blk_q, skp // blk_kv),
-        in_specs=[
-            pl.BlockSpec((1, blk_q, 1, hd),
-                         lambda bi, hi, qi, ki, *_: (bi, qi, hi, 0)),
-            pl.BlockSpec((1, blk_kv, 1, hd), _kv_index),
-            pl.BlockSpec((1, blk_kv, 1, hd), _kv_index),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, blk_q, 1, hd),
-                         lambda bi, hi, qi, ki, *_: (bi, qi, hi, 0)),
-            pl.BlockSpec((1, 1, blk_q),
-                         lambda bi, hi, qi, ki, *_: (bi, hi, qi)),
-        ],
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=[q_spec, row_spec(blk_q, _q_index)],
         scratch_shapes=[
             pltpu.VMEM((blk_q, 1), jnp.float32),    # running max
             pltpu.VMEM((blk_q, 1), jnp.float32),    # running denom
@@ -164,12 +181,12 @@ def terapipe_attention_fwd(q, k, v, ctx, *,
                           scale=scale),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, lp, hq, hd), q.dtype),
-            jax.ShapeDtypeStruct((b, hq, lp), jnp.float32),
+            jax.ShapeDtypeStruct((b, hq, lp, hd), q.dtype),
+            jax.ShapeDtypeStruct((b, hq, lp, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(ctx_arr, q, k, v)
-    return out[:, :l], lse[:, :, :l]
+    )(ctx_arr, qh, kh, vh)
+    return from_heads(out, l), lse[:, :, :l, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("ctx_len", "blk_q", "blk_kv",

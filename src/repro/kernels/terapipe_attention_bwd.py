@@ -30,7 +30,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .terapipe_attention import (DEFAULT_BLOCK_KV, DEFAULT_BLOCK_Q, align_block, _pad_seq)
+from .terapipe_attention import (DEFAULT_BLOCK_KV, DEFAULT_BLOCK_Q,
+                                 align_block, from_heads, row_spec, to_heads)
 
 
 def _masked_p(q, k, lse, ctx, l, iq, ikv, blk_q, blk_kv, scale):
@@ -62,12 +63,12 @@ def _dq_kernel(ctx_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(ikv * blk_kv < ctx + jnp.minimum((iq + 1) * blk_q, l))
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)           # (blk_q, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)           # (blk_kv, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        do = do_ref[0, :, 0, :].astype(jnp.float32)
-        lse = lse_ref[0, 0, :][:, None]                     # (blk_q, 1)
-        delta = delta_ref[0, 0, :][:, None]
+        q = q_ref[...].astype(jnp.float32)                  # (blk_q, hd)
+        k = k_ref[...].astype(jnp.float32)                  # (blk_kv, hd)
+        v = v_ref[...].astype(jnp.float32)
+        do = do_ref[...].astype(jnp.float32)
+        lse = lse_ref[...]                                  # (blk_q, 1)
+        delta = delta_ref[...]
         p = _masked_p(q, k, lse, ctx, l, iq, ikv, blk_q, blk_kv, scale)
         dp = jax.lax.dot_general(                           # dO · Vᵀ
             do, v, (((1,), (1,)), ((), ())),
@@ -79,7 +80,7 @@ def _dq_kernel(ctx_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(ikv == n_kv - 1)
     def _finalize():
-        dq_ref[0, :, 0, :] = acc_scr[...].astype(dq_ref.dtype)
+        dq_ref[...] = acc_scr[...].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(ctx_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -98,12 +99,12 @@ def _dkv_kernel(ctx_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(ikv * blk_kv < ctx + jnp.minimum((iq + 1) * blk_q, l))
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)           # (blk_q, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)           # (blk_kv, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        do = do_ref[0, :, 0, :].astype(jnp.float32)
-        lse = lse_ref[0, 0, :][:, None]
-        delta = delta_ref[0, 0, :][:, None]
+        q = q_ref[...].astype(jnp.float32)                  # (blk_q, hd)
+        k = k_ref[...].astype(jnp.float32)                  # (blk_kv, hd)
+        v = v_ref[...].astype(jnp.float32)
+        do = do_ref[...].astype(jnp.float32)
+        lse = lse_ref[...]
+        delta = delta_ref[...]
         p = _masked_p(q, k, lse, ctx, l, iq, ikv, blk_q, blk_kv, scale)
         dv_scr[...] += jax.lax.dot_general(                 # Pᵀ · dO
             p, do, (((0,), (0,)), ((), ())),
@@ -118,13 +119,13 @@ def _dkv_kernel(ctx_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when((r == rep - 1) & (iq == n_q - 1))
     def _finalize():
-        dk_ref[0, :, 0, :] = dk_scr[...].astype(dk_ref.dtype)
-        dv_ref[0, :, 0, :] = dv_scr[...].astype(dv_ref.dtype)
+        dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _pad_rows(a, pad):
-    """Pad the trailing (row) axis of (B, H, l)-shaped lse/delta."""
-    return jnp.pad(a, ((0, 0), (0, 0), (0, pad))) if pad else a
+def _rows(a, pad):
+    """(B, H, l) lse/delta -> the kernels' (B, H, l + pad, 1) column."""
+    return jnp.pad(a, ((0, 0), (0, 0), (0, pad)))[..., None]
 
 
 @functools.partial(jax.jit, static_argnames=("blk_q", "blk_kv", "interpret"))
@@ -136,7 +137,8 @@ def terapipe_attention_bwd(q, k, v, do, lse, delta, ctx, *,
 
     q/do: (B, l, Hq, hd); k/v: (B, Sk, Hkv, hd) GQA-native; lse/delta:
     (B, Hq, l) f32; ctx: int32 scalar, may be traced.  dk/dv come back in
-    the GQA-native (Hkv) layout — no repeated-head buffers anywhere.
+    the GQA-native (Hkv) layout — no repeated-head buffers anywhere.  The
+    kernels run head-major, as the forward does (``to_heads``).
     """
     b, l, hq, hd = q.shape
     sk, hkv = k.shape[1], k.shape[2]
@@ -146,10 +148,10 @@ def terapipe_attention_bwd(q, k, v, do, lse, delta, ctx, *,
     scale = 1.0 / math.sqrt(hd)
 
     l_pad, sk_pad = -l % blk_q, -sk % blk_kv
-    q, do = _pad_seq(q, l_pad), _pad_seq(do, l_pad)
-    k, v = _pad_seq(k, sk_pad), _pad_seq(v, sk_pad)
-    lse, delta = _pad_rows(lse, l_pad), _pad_rows(delta, l_pad)
-    lp, skp = q.shape[1], k.shape[1]
+    q, do = to_heads(q, l_pad), to_heads(do, l_pad)
+    k, v = to_heads(k, sk_pad), to_heads(v, sk_pad)
+    lse, delta = _rows(lse, l_pad), _rows(delta, l_pad)
+    lp, skp = q.shape[2], k.shape[2]
     ctx_arr = jnp.asarray(ctx, jnp.int32).reshape((1,))
 
     # kv / q block indices are clamped to the causal frontier (from the
@@ -157,17 +159,18 @@ def terapipe_attention_bwd(q, k, v, do, lse, delta, ctx, *,
     # block and their HBM->VMEM copies are elided (see terapipe_attention).
     def _kv_index(bi, hi, qi, ki, ctx_ref):
         last = (ctx_ref[0] + jnp.minimum((qi + 1) * blk_q, l) - 1) // blk_kv
-        return (bi, jnp.minimum(ki, last), hi // rep, 0)
+        return (bi, hi // rep, jnp.minimum(ki, last), 0)
 
-    q_spec = pl.BlockSpec((1, blk_q, 1, hd),
-                          lambda bi, hi, qi, ki, *_: (bi, qi, hi, 0))
-    kv_spec = pl.BlockSpec((1, blk_kv, 1, hd), _kv_index)
-    row_spec = pl.BlockSpec((1, 1, blk_q),
-                            lambda bi, hi, qi, ki, *_: (bi, hi, qi))
+    def _q_index(bi, hi, qi, ki, *_):
+        return (bi, hi, qi, 0)
+
+    q_spec = pl.BlockSpec((None, None, blk_q, hd), _q_index)
+    kv_spec = pl.BlockSpec((None, None, blk_kv, hd), _kv_index)
+    rspec = row_spec(blk_q, _q_index)
     dq_grid = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b, hq, lp // blk_q, skp // blk_kv),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, rspec, rspec],
         out_specs=[q_spec],
         scratch_shapes=[pltpu.VMEM((blk_q, hd), jnp.float32)],
     )
@@ -175,7 +178,7 @@ def terapipe_attention_bwd(q, k, v, do, lse, delta, ctx, *,
         functools.partial(_dq_kernel, l=l, blk_q=blk_q, blk_kv=blk_kv,
                           scale=scale),
         grid_spec=dq_grid,
-        out_shape=[jax.ShapeDtypeStruct((b, lp, hq, hd), q.dtype)],
+        out_shape=[jax.ShapeDtypeStruct((b, hq, lp, hd), q.dtype)],
         interpret=interpret,
     )(ctx_arr, q, k, v, do, lse, delta)
 
@@ -191,16 +194,13 @@ def terapipe_attention_bwd(q, k, v, do, lse, delta, ctx, *,
         first = (ki * blk_kv - ctx_ref[0]) // blk_q
         return jnp.minimum(jnp.maximum(qi, first), n_q - 1)
 
-    gq_spec = pl.BlockSpec(
-        (1, blk_q, 1, hd),
-        lambda bi, hk, ki, r, qi, ctx_ref: (
-            bi, _gq_block(qi, ki, ctx_ref), hk * rep + r, 0))
-    gkv_spec = pl.BlockSpec((1, blk_kv, 1, hd),
-                            lambda bi, hk, ki, r, qi, *_: (bi, ki, hk, 0))
-    grow_spec = pl.BlockSpec(
-        (1, 1, blk_q),
-        lambda bi, hk, ki, r, qi, ctx_ref: (
-            bi, hk * rep + r, _gq_block(qi, ki, ctx_ref)))
+    def _gq_index(bi, hk, ki, r, qi, ctx_ref):
+        return (bi, hk * rep + r, _gq_block(qi, ki, ctx_ref), 0)
+
+    gq_spec = pl.BlockSpec((None, None, blk_q, hd), _gq_index)
+    gkv_spec = pl.BlockSpec((None, None, blk_kv, hd),
+                            lambda bi, hk, ki, r, qi, *_: (bi, hk, ki, 0))
+    grow_spec = row_spec(blk_q, _gq_index)
     dkv_grid = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b, hkv, skp // blk_kv, rep, lp // blk_q),
@@ -213,8 +213,8 @@ def terapipe_attention_bwd(q, k, v, do, lse, delta, ctx, *,
         functools.partial(_dkv_kernel, l=l, blk_q=blk_q, blk_kv=blk_kv,
                           scale=scale, rep=rep),
         grid_spec=dkv_grid,
-        out_shape=[jax.ShapeDtypeStruct((b, skp, hkv, hd), k.dtype),
-                   jax.ShapeDtypeStruct((b, skp, hkv, hd), v.dtype)],
+        out_shape=[jax.ShapeDtypeStruct((b, hkv, skp, hd), k.dtype),
+                   jax.ShapeDtypeStruct((b, hkv, skp, hd), v.dtype)],
         interpret=interpret,
     )(ctx_arr, q, k, v, do, lse, delta)
-    return dq[:, :l], dk[:, :sk], dv[:, :sk]
+    return from_heads(dq, l), from_heads(dk, sk), from_heads(dv, sk)

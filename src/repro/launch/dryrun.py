@@ -2,6 +2,7 @@ import os
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
     " --xla_force_host_platform_device_count=" +
     os.environ.get("REPRO_DRYRUN_DEVICES", "512")).strip()
+os.environ["JAX_PLATFORMS"] = "cpu"          # host devices; never opens a chip
 
 """Multi-pod dry-run: AOT lower + compile every (arch × shape × mesh) cell.
 
@@ -26,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import cost_analysis_dict, use_mesh
+from repro.compat import use_mesh
 from repro.configs import ARCHS, SHAPES, get_config, input_specs, skip_reason
 from repro.core.schedules import (REGISTRY, check_virtual_stages,
                                   schedule_help, schedule_names)
@@ -110,7 +111,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
         rec["compile_s"] = time.time() - t1
 
         mem = compiled.memory_analysis()
-        cost = cost_analysis_dict(compiled)
+        cost = compiled.cost_analysis()
         hlo = compiled.as_text()
         rec["memory"] = _mem_dict(mem)
         # XLA's cost_analysis does NOT multiply while-loop bodies by their

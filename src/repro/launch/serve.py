@@ -17,6 +17,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serve import DecodeEngine, EngineConfig
 
@@ -47,6 +48,7 @@ def main(argv=None):
                     help="price the trace with simulate_stream")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     model = build_model(cfg)

@@ -28,6 +28,7 @@ from __future__ import annotations
 import argparse
 import sys
 import time
+from types import SimpleNamespace
 
 import jax
 import numpy as np
@@ -38,14 +39,17 @@ from repro.configs import get_config
 from repro.core.schedules import (check_virtual_stages, schedule_help,
                                   schedule_names)
 from repro.data.pipeline import DataPipeline, SyntheticSource
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.optim.adamw import adamw, apply_updates, cosine_schedule
 
 
 def build_value_and_grad(model, specs, mesh, args):
-    """(params, batch) -> (loss, grads) for the selected execution mode."""
-    if args.mode == "gspmd" or mesh is None:
-        return jax.value_and_grad(model.loss)
+    """``(vg_fn, param_shardings_fn)`` for the selected execution mode:
+    ``vg_fn(params, batch) -> (loss, grads)``; the shardings function is
+    None for gspmd."""
+    if args.mode == "gspmd":
+        return jax.value_and_grad(model.loss), None
     from repro.core.pipeline import (TeraPipeConfig,
                                      make_terapipe_value_and_grad)
     schedule = args.schedule
@@ -56,11 +60,12 @@ def build_value_and_grad(model, specs, mesh, args):
     slice_lens = None
     if args.mode == "terapipe" and args.dp_plan:
         # Algorithm 1 end-to-end: plan the slicing with the DP, execute it
-        from repro.core.cost_model import AnalyticCostModel, TPU_V5E
+        from repro.core.cost_model import AnalyticCostModel, hardware_for
         from repro.core.dp import (ensure_executable, optimal_slicing,
                                    plan_schedule_info)
+        hw = hardware_for(jax.devices()[0].device_kind)
         K = mesh.shape["pipe"]
-        cm = AnalyticCostModel(model.cfg, TPU_V5E,
+        cm = AnalyticCostModel(model.cfg, hw,
                                layers_per_stage=max(1, model.n_blocks // K))
         g = max(1, args.seq // 16)
         plan = optimal_slicing(cm, args.seq, K, granularity=g,
@@ -87,7 +92,7 @@ def build_value_and_grad(model, specs, mesh, args):
         from repro.core.schedule import SlicingScheme
         from repro.core.schedules import REGISTRY
         from repro.core.simulator import simulate
-        cm_u = AnalyticCostModel(model.cfg, TPU_V5E,
+        cm_u = AnalyticCostModel(model.cfg, hw,
                                  layers_per_stage=max(1, model.n_blocks // K),
                                  include_backward=False)
         D = args.microbatches
@@ -131,12 +136,24 @@ def build_value_and_grad(model, specs, mesh, args):
         schedule=schedule,
         virtual_stages=args.virtual_stages,
         use_kernel=True if args.use_kernel else None)
-    vg_fn, _ = make_terapipe_value_and_grad(model, specs, mesh, tcfg,
-                                            args.seq, args.batch)
-    return vg_fn
+    return make_terapipe_value_and_grad(model, specs, mesh, tcfg, args.seq,
+                                        args.batch)
 
 
-def main(argv=None):
+def _param_specs(model, key):
+    """The logical-axis spec tree of the model's params, from an abstract
+    trace of its init (nothing is allocated)."""
+    box = []
+
+    def trace(k):
+        params, specs = model.init(k)
+        box.append(specs)
+        return params
+    jax.eval_shape(trace, key)
+    return box[0]
+
+
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
     ap.add_argument("--smoke", action="store_true",
@@ -164,7 +181,7 @@ def main(argv=None):
     ap.add_argument("--use-kernel", action="store_true",
                     help="route attention through the Pallas flash kernels "
                     "(fused fwd+bwd; both pipeline schedules and gspmd). "
-                    "Interpret mode off-TPU — see EXPERIMENTS.md §Kernels")
+                    "Compiled on a TPU, interpret mode on the CPU")
     ap.add_argument("--unroll", action="store_true",
                     help="unrolled tick loop (debug/differential testing; "
                     "trace time grows with D*M)")
@@ -186,33 +203,69 @@ def main(argv=None):
         check_virtual_stages(sched_eff, args.virtual_stages)
     except ValueError as e:
         ap.error(str(e))
+    return args
 
+
+def setup(args: argparse.Namespace) -> SimpleNamespace:
+    """Model, initial state, mesh, the (un-jitted) train step and the data
+    pipeline for ``args``.  The pipeline modes always run the executor: on
+    one device its mesh is (data=1, pipe=1)."""
     cfg = get_config(args.arch, smoke=args.smoke)
     if args.use_kernel:
         cfg = cfg.replace(use_kernel=True)   # gspmd path; terapipe overrides
     if cfg.family == "moe":
         args.seq = max(args.seq, cfg.moe_block)
     model = build_model(cfg)
-    params, specs = model.init(jax.random.PRNGKey(args.seed))
-    opt = adamw(cosine_schedule(args.lr, args.warmup, args.steps))
-    opt_state = opt.init(params)
-
-    # pipeline modes need a multi-device mesh; build one if devices allow
+    key = jax.random.PRNGKey(args.seed)
+    specs = _param_specs(model, key)
     mesh = None
-    if args.mode in ("terapipe", "gpipe") and len(jax.devices()) > 1:
+    if args.mode in ("terapipe", "gpipe"):
         n = len(jax.devices())
         pipe = min(4, n)
         mesh = make_mesh((n // pipe, pipe), ("data", "pipe"))
-    vg_fn = build_value_and_grad(model, specs, mesh, args)
+    vg_fn, shardings_fn = build_value_and_grad(model, specs, mesh, args)
+    if shardings_fn is None:
+        params = model.init(key)[0]
+    else:
+        # each device materializes only its own stage's share of the model
+        params = jax.jit(lambda k: model.init(k)[0],
+                         out_shardings=shardings_fn(specs))(key)
+    opt = adamw(cosine_schedule(args.lr, args.warmup, args.steps))
+    opt_state = opt.init(params)
 
     def train_step(params, opt_state, batch):
         loss, grads = vg_fn(params, batch)
         updates, opt_state = opt.update(grads, opt_state, params)
         return apply_updates(params, updates), opt_state, loss
 
-    ctx = use_mesh(mesh) if mesh is not None else None
-    if ctx is not None:
-        ctx.__enter__()
+    extra = None
+    if cfg.family == "vlm":
+        extra = {"patch_embeds": ((cfg.n_patches, cfg.d_model), np.float32)}
+    if cfg.family == "encdec":
+        extra = {"frames": ((args.seq, cfg.d_model), np.float32)}
+    # vlm: the image patches prefix the token stream, so only seq - patches
+    # positions carry text tokens
+    text_len = args.seq - cfg.n_patches if cfg.family == "vlm" else args.seq
+    data = DataPipeline(SyntheticSource(cfg.vocab_size, args.seed),
+                        args.batch, text_len, extra_specs=extra)
+    return SimpleNamespace(cfg=cfg, model=model, params=params,
+                           opt_state=opt_state, mesh=mesh,
+                           train_step=train_step, data=data)
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    enable_compile_cache()
+    run = setup(args)
+    params, opt_state, data = run.params, run.opt_state, run.data
+    with use_mesh(run.mesh):
+        return _supervise(args, params, opt_state, data, run.train_step)
+
+
+def _supervise(args, params, opt_state, data, train_step):
+    """The step loop: checkpoints, and restore-and-retry on a fault.  A
+    step is retried at most once; a step that faults again is a
+    deterministic fault (an OOM, a compile error) and raises."""
     ckpt = CheckpointManager(args.checkpoint_dir) if args.checkpoint_dir else None
     # donation deletes the inputs of every dispatched step — only safe when a
     # checkpoint can restore them; without one, the live references ARE the
@@ -226,17 +279,6 @@ def main(argv=None):
         {"params": params, "opt": opt_state})
     state_template["step"] = 0
 
-    extra = None
-    if cfg.family == "vlm":
-        extra = {"patch_embeds": ((cfg.n_patches, cfg.d_model), np.float32)}
-    if cfg.family == "encdec":
-        extra = {"frames": ((args.seq, cfg.d_model), np.float32)}
-    # vlm: the image patches prefix the token stream, so only seq - patches
-    # positions carry text tokens
-    text_len = args.seq - cfg.n_patches if cfg.family == "vlm" else args.seq
-    data = DataPipeline(SyntheticSource(cfg.vocab_size, args.seed),
-                        args.batch, text_len, extra_specs=extra)
-
     start_step = 0
     if ckpt and args.resume and ckpt.latest_step() is not None:
         state = ckpt.restore(target=state_template)
@@ -244,24 +286,30 @@ def main(argv=None):
                                          int(state["step"]))
         print(f"[resume] restored step {start_step}")
 
-    failed_once = False
+    injected = False
+    faulted = set()        # steps that faulted once
     step = start_step
     t_last, tok_count = time.time(), 0
     while step < args.steps:
         try:
             batch = data.batch_at(step)
             out = step_fn(params, opt_state, batch)
-            if args.simulate_failure_at == step and not failed_once:
+            if args.simulate_failure_at == step and not injected:
                 # inject AFTER dispatch: with donation on, params/opt_state
                 # are now deleted — exactly the state a real mid-step fault
                 # leaves behind
-                failed_once = True
+                injected = True
                 raise RuntimeError("injected fault (simulate-failure-at)")
             params, opt_state, loss = out
             tok_count += batch["tokens"].size
             step += 1
-        except Exception as e:  # supervisor: restore-and-continue
+        except Exception as e:  # supervisor: restore-and-retry, once
             print(f"[fault] step {step}: {e}", file=sys.stderr)
+            if step in faulted:
+                print(f"[fault] step {step} faulted again; not retrying",
+                      file=sys.stderr)
+                raise
+            faulted.add(step)
             if ckpt and ckpt.latest_step() is not None:
                 state = ckpt.restore(target=state_template)
                 params, opt_state, step = (state["params"], state["opt"],
@@ -274,13 +322,11 @@ def main(argv=None):
                 print("[fault] no checkpoint saved yet and donation has "
                       "deleted the step inputs; cannot retry", file=sys.stderr)
                 raise
-            if failed_once and args.simulate_failure_at >= 0:
-                # no checkpointing configured: donation is off, so the
-                # pre-step params/opt_state references are intact — retry
-                print("[fault] no checkpoint dir; retrying step with rescue "
-                      "references")
-                continue
-            raise
+            # no checkpointing configured: donation is off, so the pre-step
+            # params/opt_state references are intact — retry
+            print("[fault] no checkpoint dir; retrying step with rescue "
+                  "references")
+            continue
 
         if step % args.log_every == 0:
             dt = time.time() - t_last
@@ -296,8 +342,6 @@ def main(argv=None):
         ckpt.save(args.steps, {"params": params, "opt": opt_state,
                                "step": args.steps})
     print(f"done: {args.steps} steps, final loss {float(loss):.4f}")
-    if ctx is not None:
-        ctx.__exit__(None, None, None)
     return float(loss)
 
 

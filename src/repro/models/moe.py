@@ -29,21 +29,18 @@ from .layers import ffn as dense_ffn, init_ffn
 def _dispatch_axes(n_groups: int):
     """Data axes to shard_map the dispatch over, or None.
 
-    Skips when: no activation sharding configured, group count not divisible,
-    we are already inside a shard_map (axes Manual — TeraPipe pipeline), or
-    jax is too old for the subset-axes shard_map API (the dispatch then runs
-    under plain GSPMD propagation — correct, just without the forced
-    group-parallel layout)."""
+    Skips when no activation sharding is configured, the group count is
+    not divisible, or we are already inside a shard_map (axes Manual —
+    TeraPipe pipeline); the dispatch then runs under plain GSPMD
+    propagation."""
     from .common import _ACT_AXES
-    from repro.compat import HAS_SHARD_MAP, auto_axis_names, current_mesh
-    if not _ACT_AXES or not HAS_SHARD_MAP:
+    from repro.compat import auto_axis_names, current_mesh
+    if not _ACT_AXES:
         return None
     mesh = current_mesh()
     if mesh is None:
         return None
     usable = auto_axis_names(mesh)
-    if usable is None:
-        return None
     total = 1
     for a in _ACT_AXES:
         if a not in usable:

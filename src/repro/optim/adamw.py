@@ -69,7 +69,9 @@ def adamw(lr: Callable | float, *, b1: float = 0.9, b2: float = 0.95,
     lr_fn = lr if callable(lr) else constant_schedule(lr)
 
     def init(params):
-        zeros = lambda p: jnp.zeros(p.shape, jnp.float32)
+        # zeros_like keeps each param's sharding: the moments of a stage's
+        # weights live on that stage's devices
+        zeros = lambda p: jnp.zeros_like(p, jnp.float32)
         master = (jax.tree.map(lambda p: p.astype(jnp.float32), params)
                   if master_weights else None)
         return AdamWState(jnp.zeros((), jnp.int32),

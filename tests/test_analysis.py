@@ -20,7 +20,7 @@ from jax.sharding import PartitionSpec as P
 from repro.analysis import count_eqns, errors
 from repro.analysis import hlo as ahlo
 from repro.analysis import rules
-from repro.compat import make_mesh, shard_map
+from repro.compat import make_mesh
 from repro.core.schedules import (KIND_BWD, KIND_BWD_INPUT, KIND_FWD,
                                   KIND_IDLE, CommPlan,
                                   ScheduleValidationError, get_schedule)
@@ -33,7 +33,7 @@ def _pipe_mesh():
 
 
 def _smap(f):
-    return shard_map(f, mesh=_pipe_mesh(), in_specs=P("pipe"),
+    return jax.shard_map(f, mesh=_pipe_mesh(), in_specs=P("pipe"),
                      out_specs=P("pipe"), check_vma=False)
 
 
@@ -112,11 +112,11 @@ def test_ring_match_flags_undeclared_ring_k4():
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
         from repro.analysis import rules, errors
-        from repro.compat import make_mesh, shard_map
+        from repro.compat import make_mesh
         from repro.core.schedules import CommPlan
         mesh = make_mesh((4,), ("pipe",))
         ident = [(j, j) for j in range(4)]
-        f = shard_map(lambda x: jax.lax.ppermute(x, "pipe", ident),
+        f = jax.shard_map(lambda x: jax.lax.ppermute(x, "pipe", ident),
                       mesh=mesh, in_specs=P("pipe"), out_specs=P("pipe"),
                       check_vma=False)
         jaxpr = jax.make_jaxpr(f)(jnp.zeros((4, 4)))
@@ -128,7 +128,7 @@ def test_ring_match_flags_undeclared_ring_k4():
         assert "neither the declared forward ring" in errs[0].message
         # and the true rings pass
         fwd = [(j, (j + 1) % 4) for j in range(4)]
-        g = shard_map(lambda x: jax.lax.ppermute(x, "pipe", fwd),
+        g = jax.shard_map(lambda x: jax.lax.ppermute(x, "pipe", fwd),
                       mesh=mesh, in_specs=P("pipe"), out_specs=P("pipe"),
                       check_vma=False)
         jg = jax.make_jaxpr(g)(jnp.zeros((4, 4)))
@@ -166,12 +166,12 @@ def test_repeated_kv_rule_flags_broadcast():
     k = jnp.zeros((1, sk, 1, 16))
     jaxpr = jax.make_jaxpr(
         lambda k: jnp.broadcast_to(k, (1, sk, hq, 16)))(k)
-    errs = errors(rules.check_repeated_kv(jaxpr, sk=sk, hq=hq, hkv=hkv))
+    errs = errors(rules.check_kv_repeat(jaxpr, sk=sk, hq=hq, hkv=hkv))
     assert errs, "GQA-repeated KV broadcast not flagged"
     assert errs[0].rule == "buffer.repeated-kv"
     assert errs[0].eqn == "broadcast_in_dim"
     # dense heads: the rule is a no-op by definition
-    assert not rules.check_repeated_kv(jaxpr, sk=sk, hq=hq, hkv=hq)
+    assert not rules.check_kv_repeat(jaxpr, sk=sk, hq=hq, hkv=hq)
 
 
 # ---------------------------------------------------------------------------

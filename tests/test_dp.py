@@ -241,3 +241,12 @@ def test_straggler_replan_improves():
     lat_n = simulate(sch_n, K, t, stage_slowdown=slow)
     lat_r = simulate(sch_r, K, t, stage_slowdown=slow)
     assert lat_r <= lat_n * 1.05   # replan never significantly worse
+
+
+def test_hardware_for_device_kind():
+    """``train --dp-plan`` plans with the spec of the device it runs on;
+    a kind with no spec is an error, never a silent default."""
+    from repro.core.cost_model import hardware_for
+    assert hardware_for("TPU v5 lite") is TPU_V5E
+    with pytest.raises(ValueError, match="no HardwareSpec"):
+        hardware_for("cpu")

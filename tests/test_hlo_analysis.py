@@ -29,8 +29,7 @@ def test_scan_trip_count_exact():
                   jax.ShapeDtypeStruct((16, 16), jnp.float32))
     true_flops = 7 * 2 * 8 * 16 * 16
     assert analyze(co.as_text())["flops"] == true_flops
-    from repro.compat import cost_analysis_dict
-    assert cost_analysis_dict(co)["flops"] < true_flops   # XLA's undercount
+    assert co.cost_analysis()["flops"] < true_flops   # XLA's undercount
 
 
 def test_nested_scan():
@@ -60,7 +59,7 @@ def test_plain_matmul_and_batched_dot():
 def test_collective_bytes_sharded(tmp_path):
     """Sharded contraction -> all-reduce; analyzer counts ring-weighted
     per-device wire bytes.  Runs in a subprocess (needs >1 device)."""
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
                PYTHONPATH=str(Path(__file__).resolve().parents[1] / "src"))
     code = textwrap.dedent("""

@@ -78,3 +78,16 @@ def test_kernel_softmax_stability():
     ref = terapipe_attention_ref(q, k, v, 64)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("backend,interpret", [
+    ("cpu", True), ("tpu", False), ("gpu", None)])
+def test_interpret_mode_follows_backend(monkeypatch, backend, interpret):
+    """Interpret mode on the CPU, compiled on a TPU, an error elsewhere —
+    decided by the backend, with no option to override it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="cannot run on 'gpu'"):
+            ops._interpret()
+    else:
+        assert ops._interpret() is interpret

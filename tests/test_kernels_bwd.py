@@ -99,7 +99,7 @@ def test_fused_vjp_jaxpr_clean_via_analyzer():
     Pallas VMEM estimator sees all three kernels under the 16 MiB budget —
     the same rules `make lint-ir` runs over the schedule matrix."""
     from repro.analysis import errors, raise_on_errors
-    from repro.analysis.rules import (check_repeated_kv, check_score_matrix,
+    from repro.analysis.rules import (check_kv_repeat, check_score_matrix,
                                       check_vmem)
     l, ctx, hq, hkv, hd = 96, 160, 4, 1, 64
     sk = ctx + l
@@ -113,7 +113,7 @@ def test_fused_vjp_jaxpr_clean_via_analyzer():
 
     jaxpr = jax.make_jaxpr(grads)(q, k, v)
     raise_on_errors(check_score_matrix(jaxpr, l=l, sk=sk)
-                    + check_repeated_kv(jaxpr, sk=sk, hq=hq, hkv=hkv),
+                    + check_kv_repeat(jaxpr, sk=sk, hq=hq, hkv=hkv),
                     context="fused-vjp")
     vmem = check_vmem(jaxpr)
     kernels = {f.data["kernel"] for f in vmem}

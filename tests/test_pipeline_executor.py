@@ -61,7 +61,7 @@ def test_rolled_matches_unrolled_uniform_and_nonuniform():
                 with use_mesh(mesh):
                     lf, _ = make_terapipe_loss(model, specs, mesh, tcfg, S, B)
                     losses[unroll] = float(jax.jit(lf)(params, batch))
-                    grads[unroll] = jax.grad(lf)(params, batch)
+                    grads[unroll] = jax.jit(jax.grad(lf))(params, batch)
             assert abs(losses[False] - losses[True]) < 1e-5 * max(
                 1.0, abs(losses[True])), (desc, losses)
             gerr = max(jax.tree.leaves(
@@ -113,7 +113,7 @@ def test_interleaved_matches_contiguous_and_reference():
                 with use_mesh(mesh):
                     lf, _ = make_terapipe_loss(model, specs, mesh, tcfg, S, B)
                     losses[tag] = float(jax.jit(lf)(params, batch))
-                    grads[tag] = jax.grad(lf)(params, batch)
+                    grads[tag] = jax.jit(jax.grad(lf))(params, batch)
             assert abs(losses["K2V2"] - losses["K4V1"]) < 1e-5 * max(
                 1.0, abs(losses["K4V1"])), (desc, losses)
             gerr = max(jax.tree.leaves(
@@ -396,3 +396,37 @@ def test_vg_jaxpr_size_independent_of_DMV_every_schedule():
         _trace_vg(4, "interleaved-1f1b", 2, n_layers=4),
         _trace_vg(4, "interleaved-1f1b", 4, n_layers=4),
         label="interleaved-1f1b V 2->4"))
+
+
+@pytest.mark.parametrize("use_kernel", [False, True])
+def test_one_device_executor_matches_model_loss(use_kernel):
+    """On one device the executor runs on a (data=1, pipe=1) mesh — the
+    path ``train --mode terapipe`` takes on a single chip — and its loss
+    and grads match ``model.loss`` with the jnp attention and with the
+    Pallas kernels."""
+    from repro.compat import make_mesh, use_mesh
+    from repro.core.pipeline import (TeraPipeConfig,
+                                     make_terapipe_value_and_grad)
+    from repro.models import build_model
+    from repro.models.common import ModelConfig
+
+    cfg = ModelConfig(name="t", family="dense", n_layers=2, d_model=64,
+                      n_heads=4, n_kv_heads=2, d_ff=128, vocab_size=256,
+                      dtype=jnp.float32, remat=False)
+    model = build_model(cfg)
+    params, specs = model.init(jax.random.PRNGKey(0))
+    B, S = 2, 32
+    rng = jax.random.PRNGKey(3)
+    batch = {"tokens": jax.random.randint(rng, (B, S), 0, cfg.vocab_size),
+             "labels": jax.random.randint(rng, (B, S), 0, cfg.vocab_size)}
+    mesh = make_mesh((1, 1), ("data", "pipe"))
+    tcfg = TeraPipeConfig(n_token_slices=4, cache_dtype=jnp.float32,
+                          use_kernel=use_kernel)
+    with use_mesh(mesh):
+        vg, _ = make_terapipe_value_and_grad(model, specs, mesh, tcfg, S, B)
+        loss, grads = jax.jit(vg)(params, batch)
+    lref, gref = jax.value_and_grad(model.loss)(params, batch)
+    assert abs(float(loss) - float(lref)) < 2e-5, (float(loss), float(lref))
+    for g, r in zip(jax.tree.leaves(grads), jax.tree.leaves(gref)):
+        err = float(jnp.max(jnp.abs(g - r)) / (1e-6 + jnp.max(jnp.abs(r))))
+        assert err < 2e-3, err

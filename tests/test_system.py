@@ -9,12 +9,13 @@ from pathlib import Path
 
 import jax
 import numpy as np
+import pytest
 
 SRC = str(Path(__file__).resolve().parents[1] / "src")
 
 
 def _run_subprocess(code: str, devices: int = 8) -> str:
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
                PYTHONPATH=SRC)
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
@@ -241,3 +242,52 @@ def test_train_driver_terapipe_mode():
         print("TRAIN-TP-OK")
     """, devices=4)
     assert "TRAIN-TP-OK" in out
+
+
+@pytest.mark.parametrize("with_checkpoint", [False, True])
+def test_supervisor_retries_a_step_once(tmp_path, capsys, with_checkpoint):
+    """A step that faults again after its retry is a deterministic fault
+    (here a trace-time error on one batch shape, as a compile error or an
+    OOM would be): the supervisor raises instead of restoring forever."""
+    import argparse
+    import jax.numpy as jnp
+    from repro.launch import train
+
+    class Data:
+        def batch_at(self, step):
+            return {"tokens": np.zeros((1, 3 if step == 2 else 2), np.int32)}
+
+    def train_step(params, opt_state, batch):
+        if batch["tokens"].shape[1] == 3:
+            raise RuntimeError("deterministic fault")
+        return params + 1, opt_state, jnp.float32(0)
+
+    args = argparse.Namespace(
+        checkpoint_dir=str(tmp_path) if with_checkpoint else None,
+        checkpoint_every=1, resume=False, steps=4, simulate_failure_at=-1,
+        log_every=100)
+    with pytest.raises(RuntimeError, match="deterministic fault"):
+        train._supervise(args, jnp.zeros(2), jnp.zeros(2), Data(),
+                         train_step)
+    err = capsys.readouterr().err
+    assert err.count("[fault] step 2: deterministic fault") == 2, err
+    assert "faulted again; not retrying" in err
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_placement(monkeypatch, tmp_path, from_env):
+    """The entry points' compile cache: where JAX_COMPILATION_CACHE_DIR
+    says, else one fixed directory in the checkout."""
+    from repro.launch.compile_cache import REPO_CACHE_DIR, enable_compile_cache
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    old = jax.config.jax_compilation_cache_dir
+    try:
+        path = enable_compile_cache()   # nothing compiles while it is set
+        want = str(tmp_path) if from_env else str(REPO_CACHE_DIR)
+        assert path == want == jax.config.jax_compilation_cache_dir
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old)
+    assert REPO_CACHE_DIR == Path(__file__).resolve().parents[1] / ".jax_cache"
