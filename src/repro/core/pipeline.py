@@ -113,6 +113,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import tracing
 from repro.core.schedules import (KIND_BWD_INPUT, KIND_BWD_WEIGHT, KIND_FWD, get_schedule, interleave_stacked, schedule_names, uninterleave_stacked)
 from repro.models import Model, build_model
 from repro.models.common import ModelConfig, rms_norm
@@ -299,6 +300,7 @@ class _Plan:
         # onto valid entries (overwritten-before-read invariant, DESIGN §3)
         self.cache_len = L if self.uniform else L + l
 
+    @jax.named_scope(tracing.EMBED)
     def prefix(self, params, batch):
         """Shared pre-pipeline prologue: embed -> pre groups -> activation
         dtype -> (non-uniform) seq pad so a short slice's l_max-window never
@@ -447,6 +449,7 @@ def _make_pipeline_body(p: _Plan):
     fwd_perm = [(j, (j + 1) % K) for j in range(K)]
     rev_perm = [(j, (j - 1) % K) for j in range(K)]
 
+    @jax.named_scope(tracing.HEAD_LOSS)
     def slice_loss(x_out, head_p, labels_sl, mask):
         """Per-slice LM loss contribution, pre-normalized by the GLOBAL
         token count (so the accumulated sum is the mean loss and a unit
@@ -488,6 +491,7 @@ def _make_pipeline_body(p: _Plan):
                 lambda a: jax.lax.dynamic_index_in_dim(a, v_idx, 0,
                                                        keepdims=False), tree)
 
+        @jax.named_scope(tracing.CACHE)
         def put_chunk(tree, sub, v_idx):
             if V == 1:
                 return jax.tree.map(lambda a, c: c[None], tree, sub)
@@ -546,31 +550,55 @@ def _make_pipeline_body(p: _Plan):
             # positions anyway).  Only applied inside the fwd branch — idle
             # and bwd ticks must not mutate cache state.
             fresh = sl_idx == 0
-            caches_in = tree_where(fresh,
-                                   jax.tree.map(jnp.zeros_like, caches_c),
-                                   caches_c)
+            with jax.named_scope(tracing.CACHE):
+                caches_in = tree_where(fresh,
+                                       jax.tree.map(jnp.zeros_like, caches_c),
+                                       caches_c)
+
+            @jax.named_scope(tracing.CACHE)
+            def save_slot(c, keys, x, tree):
+                """Write a unit's activation and cache tree into its slot of
+                the (V, R) ring buffers ``keys`` (see residual_spread)."""
+                kx, kt = keys
+                slot = i_c % R
+                return dict(c, **{
+                    kx: jax.lax.dynamic_update_slice(
+                        c[kx], x[None, None], (v_idx, slot, 0, 0, 0)),
+                    kt: jax.tree.map(
+                        lambda buf, a: jax.lax.dynamic_update_slice(
+                            buf, a[None, None], (v_idx, slot) + (0,) * a.ndim),
+                        c[kt], tree)})
+
+            @jax.named_scope(tracing.CACHE)
+            def read_slot(c, keys):
+                """The unit's activation and cache tree from its slot of the
+                (V, R) ring buffers ``keys``."""
+                kx, kt = keys
+                slot = i_c % R
+                x = jax.lax.dynamic_slice(
+                    c[kx], (v_idx, slot, 0, 0, 0),
+                    (1, 1, mb_local, l, d_model))[0, 0]
+                tree = jax.tree.map(
+                    lambda buf: jax.lax.dynamic_slice(
+                        buf, (v_idx, slot) + (0,) * (buf.ndim - 2),
+                        (1, 1) + buf.shape[2:])[0, 0], c[kt])
+                return x, tree
 
             def idle_branch(c):
                 return c
 
+            @jax.named_scope(tracing.TICK_FWD)
             def fwd_branch(c):
                 x_out, caches_out = p.stage_apply(params_c, x_in, caches_in,
                                                   ctx)
                 c = dict(c, x=x_out,
                          caches=put_chunk(c["caches"], caches_out, v_idx))
                 if has_bwd:
-                    # save the unit's inputs for its bwd tick's recompute
-                    slot = i_c % R
-                    c = dict(
-                        c,
-                        rx=jax.lax.dynamic_update_slice(
-                            c["rx"], x_in[None, None],
-                            (v_idx, slot, 0, 0, 0)),
-                        rc=jax.tree.map(
-                            lambda buf, cc: jax.lax.dynamic_update_slice(
-                                buf, cc[None, None],
-                                (v_idx, slot) + (0,) * cc.ndim),
-                            c["rc"], caches_in))
+                    # save the unit's inputs for its bwd tick's recompute;
+                    # the slot is released at the retiring backward tick
+                    # (fused bwd, or W when the schedule splits the backward
+                    # — B only reads it)
+                    c = save_slot(c, ("rx", "rc"), x_in, caches_in)
                 return c
 
             if has_bwd:
@@ -594,20 +622,6 @@ def _make_pipeline_body(p: _Plan):
                 # the last global stage seeds from its own loss, not the ring
                 g_cot = jnp.where(is_last, jnp.zeros_like(g_ring), g_ring)
                 seed = jnp.where(is_last, jnp.float32(1), jnp.float32(0))
-
-                def read_residual(c):
-                    """The unit's saved fwd inputs; the slot is released at
-                    the retiring backward tick (fused bwd, or W when the
-                    schedule splits the backward — B only reads it)."""
-                    slot = i_c % R
-                    x_saved = jax.lax.dynamic_slice(
-                        c["rx"], (v_idx, slot, 0, 0, 0),
-                        (1, 1, mb_local, l, d_model))[0, 0]
-                    c_saved = jax.tree.map(
-                        lambda buf: jax.lax.dynamic_slice(
-                            buf, (v_idx, slot) + (0,) * (buf.ndim - 2),
-                            (1, 1) + buf.shape[2:])[0, 0], c["rc"])
-                    return x_saved, c_saved
 
                 def unit(sp, xi, ci, hp):
                     xo, co = p.stage_apply(sp, xi, ci, ctx)
@@ -655,53 +669,39 @@ def _make_pipeline_body(p: _Plan):
                                 d_ln=c["d_ln"] + d_hp[0],
                                 d_wh=c["d_wh"] + d_hp[1])
 
+                @jax.named_scope(tracing.TICK_BWD)
                 def bwd_branch(c):
                     """Fused backward: one vjp over params AND inputs."""
-                    x_saved, c_saved = read_residual(c)
+                    x_saved, c_saved = read_slot(c, ("rx", "rc"))
                     (_, _, ls), vjp = jax.vjp(unit, params_c, x_saved,
                                               c_saved, head_p)
                     d_sp, d_x_in, d_c_in, d_hp = vjp(out_cotangent(c))
                     return apply_param_cots(
                         apply_input_cots(c, d_x_in, d_c_in, ls), d_sp, d_hp)
 
+                @jax.named_scope(tracing.TICK_BWD_INPUT)
                 def bwd_input_branch(c):
                     """B: vjp over the unit's INPUTS only — the cotangent
                     leaves on the reverse ring THIS tick; the output
                     cotangent it consumed is saved for the matching W."""
-                    x_saved, c_saved = read_residual(c)
+                    x_saved, c_saved = read_slot(c, ("rx", "rc"))
                     (_, _, ls), vjp = jax.vjp(
                         lambda xi, ci: unit(params_c, xi, ci, head_p),
                         x_saved, c_saved)
                     d_xo, d_co, d_ls = out_cotangent(c)
                     d_x_in, d_c_in = vjp((d_xo, d_co, d_ls))
-                    slot = i_c % R
-                    c = dict(
-                        c,
-                        rg=jax.lax.dynamic_update_slice(
-                            c["rg"], d_xo[None, None],
-                            (v_idx, slot, 0, 0, 0)),
-                        rgc=jax.tree.map(
-                            lambda buf, g: jax.lax.dynamic_update_slice(
-                                buf, g[None, None],
-                                (v_idx, slot) + (0,) * g.ndim),
-                            c["rgc"], d_co))
+                    c = save_slot(c, ("rg", "rgc"), d_xo, d_co)
                     return apply_input_cots(c, d_x_in, d_c_in, ls)
 
+                @jax.named_scope(tracing.TICK_BWD_WEIGHT)
                 def bwd_weight_branch(c):
                     """W: vjp over the unit's PARAMS (stage chunk + head),
                     replaying the saved residual against the output
                     cotangent its B consumed; releases the residual slot.
                     The loss seed recomputes from is_last — only the
                     array-shaped cotangents need saving."""
-                    x_saved, c_saved = read_residual(c)
-                    slot = i_c % R
-                    g_saved = jax.lax.dynamic_slice(
-                        c["rg"], (v_idx, slot, 0, 0, 0),
-                        (1, 1, mb_local, l, d_model))[0, 0]
-                    gc_saved = jax.tree.map(
-                        lambda buf: jax.lax.dynamic_slice(
-                            buf, (v_idx, slot) + (0,) * (buf.ndim - 2),
-                            (1, 1) + buf.shape[2:])[0, 0], c["rgc"])
+                    x_saved, c_saved = read_slot(c, ("rx", "rc"))
+                    g_saved, gc_saved = read_slot(c, ("rg", "rgc"))
                     _, vjp = jax.vjp(
                         lambda sp, hp: unit(sp, x_saved, c_saved, hp),
                         params_c, head_p)
@@ -729,19 +729,21 @@ def _make_pipeline_body(p: _Plan):
             # only on the tick the schedule delivers it (validate()), so
             # off-kind sends are inert.
             x_send = out["x"]
-            x_next = jax.lax.ppermute(x_send, tcfg.pipe_axis, fwd_perm)
-            out = dict(out, x=x_next)
-            if has_bwd:
-                out = dict(out, g=jax.lax.ppermute(out["g"], tcfg.pipe_axis,
-                                                   rev_perm))
-            else:
+            with jax.named_scope(tracing.RING):
+                x_next = jax.lax.ppermute(x_send, tcfg.pipe_axis, fwd_perm)
+                out = dict(out, x=x_next)
+                if has_bwd:
+                    out = dict(out, g=jax.lax.ppermute(
+                        out["g"], tcfg.pipe_axis, rev_perm))
+            if not has_bwd:
                 # per-work-item output ring written by every rank; only the
                 # last rank's rows are read.  Idle ticks land in the dump
                 # row DM; under interleaving an item's writes ascend in
                 # chunk order, so the final chunk V-1 lands last.
                 row = jnp.where(branch > 0, i_c, DM)
-                out = dict(out, out=jax.lax.dynamic_update_slice(
-                    out["out"], x_send[None], (row, 0, 0, 0)))
+                with jax.named_scope(tracing.REASSEMBLE):
+                    out = dict(out, out=jax.lax.dynamic_update_slice(
+                        out["out"], x_send[None], (row, 0, 0, 0)))
             return out, None
 
         carry = {
@@ -792,13 +794,15 @@ def _make_pipeline_body(p: _Plan):
                 carry["caches"])
             return carry["out"], final_caches
         axes_all = (tcfg.pipe_axis,) + tuple(tcfg.data_axes)
-        loss = jax.lax.psum(carry["loss"], axes_all)
-        d_ln = jax.lax.psum(carry["d_ln"], axes_all)
-        d_wh = jax.lax.psum(carry["d_wh"], axes_all)
-        d_emb = jax.lax.psum(carry["d_emb"], tcfg.pipe_axis)  # rank0 nonzero
-        d_stage = jax.tree.map(
-            lambda a: jax.lax.psum(a.reshape((V * bps,) + a.shape[2:]),
-                                   tuple(tcfg.data_axes)), carry["d_stage"])
+        with jax.named_scope(tracing.GRAD_REDUCE):
+            loss = jax.lax.psum(carry["loss"], axes_all)
+            d_ln = jax.lax.psum(carry["d_ln"], axes_all)
+            d_wh = jax.lax.psum(carry["d_wh"], axes_all)
+            d_emb = jax.lax.psum(carry["d_emb"], tcfg.pipe_axis)  # rank0 only
+            d_stage = jax.tree.map(
+                lambda a: jax.lax.psum(a.reshape((V * bps,) + a.shape[2:]),
+                                       tuple(tcfg.data_axes)),
+                carry["d_stage"])
         return loss, d_stage, d_ln, d_wh, d_emb
 
     return pipeline_body
@@ -839,10 +843,9 @@ def _make_loss_from_plan(p: _Plan):
         in_specs=(p.stage_in_specs, p.x_spec),
         out_specs=out_specs, check_vma=False)
 
-    def loss_fn(params, batch):
-        x = p.prefix(params, batch)
-        stage_params = p.prep_stage_params(params["groups"][main.name])
-        out = shmap(stage_params, x)
+    @jax.named_scope(tracing.REASSEMBLE)
+    def reassemble(out):
+        """The last rank's per-item rows of the output ring -> (B, L, d)."""
         rows = DM + 1                         # incl. the idle-tick dump row
         out_last = jax.lax.slice_in_dim(out, (K - 1) * rows,
                                         (K - 1) * rows + DM, axis=0)
@@ -862,13 +865,18 @@ def _make_loss_from_plan(p: _Plan):
             o = jnp.concatenate(segs, axis=3)         # (D, data, mb, L, d)
             o = jnp.transpose(o, (1, 0, 2, 3, 4))
             x_final = o.reshape(B, L, d_model)
-        x_final = jax.lax.with_sharding_constraint(
+        return jax.lax.with_sharding_constraint(
             x_final, NamedSharding(mesh, P(tcfg.data_axes, None, None)))
 
-        for g in post:
-            x_final = _scan_full(g, params["groups"][g.name], x_final,
-                                 cfg.remat)
-        return model.head_loss(params, x_final, batch["labels"])
+    def loss_fn(params, batch):
+        x = p.prefix(params, batch)
+        stage_params = p.prep_stage_params(params["groups"][main.name])
+        x_final = reassemble(shmap(stage_params, x))
+        with jax.named_scope(tracing.HEAD_LOSS):
+            for g in post:
+                x_final = _scan_full(g, params["groups"][g.name], x_final,
+                                     cfg.remat)
+            return model.head_loss(params, x_final, batch["labels"])
 
     return loss_fn
 

@@ -16,6 +16,9 @@ import dataclasses
 from typing import Dict, Iterator, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
+
+from repro import tracing
 
 
 @dataclasses.dataclass
@@ -69,17 +72,18 @@ class DataPipeline:
         self.local_batch = self.global_batch // self.n_shards
 
     def batch_at(self, step: int) -> Dict[str, np.ndarray]:
-        toks = self.source.tokens_at(step, self.shard,
-                                     (self.local_batch, self.seq_len + 1))
-        batch = {"tokens": toks[:, :-1].astype(np.int32),
-                 "labels": toks[:, 1:].astype(np.int32)}
-        if self.extra_specs:
-            rng = np.random.default_rng(
-                np.random.SeedSequence([17, step, self.shard]))
-            for name, (shape, dtype) in self.extra_specs.items():
-                batch[name] = rng.standard_normal(
-                    (self.local_batch,) + tuple(shape)).astype(dtype)
-        return batch
+        with TraceAnnotation(tracing.BATCH_AT):
+            toks = self.source.tokens_at(step, self.shard,
+                                         (self.local_batch, self.seq_len + 1))
+            batch = {"tokens": toks[:, :-1].astype(np.int32),
+                     "labels": toks[:, 1:].astype(np.int32)}
+            if self.extra_specs:
+                rng = np.random.default_rng(
+                    np.random.SeedSequence([17, step, self.shard]))
+                for name, (shape, dtype) in self.extra_specs.items():
+                    batch[name] = rng.standard_normal(
+                        (self.local_batch,) + tuple(shape)).astype(dtype)
+            return batch
 
     def __iter__(self) -> Iterator[Dict[str, np.ndarray]]:
         step = 0

@@ -22,6 +22,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
+
 from .decode_attention import decode_attention_kernel
 from .terapipe_attention import (DEFAULT_BLOCK_KV, DEFAULT_BLOCK_Q,
                                  terapipe_attention_fwd)
@@ -59,8 +61,9 @@ def _make_flash_attention(blk_q: int, blk_kv: int, interpret: bool):
     def _bwd(res, g):
         q, k, v, ctx, out, lse = res
         # delta = rowsum(dO ∘ O): linear in l, plain jnp
-        delta = jnp.einsum("blhd,blhd->bhl", g.astype(jnp.float32),
-                           out.astype(jnp.float32))
+        with jax.named_scope(tracing.ATTN_RELAYOUT):
+            delta = jnp.einsum("blhd,blhd->bhl", g.astype(jnp.float32),
+                               out.astype(jnp.float32))
         dq, dk, dv = terapipe_attention_bwd(
             q, k, v, g.astype(q.dtype), lse, delta, ctx,
             blk_q=blk_q, blk_kv=blk_kv, interpret=interpret)

@@ -40,6 +40,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import tracing
+
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_KV = 128
 MXU_ALIGN = 128
@@ -145,9 +147,10 @@ def terapipe_attention_fwd(q, k, v, ctx, *,
     blk_kv = align_block(blk_kv, sk)
     scale = 1.0 / math.sqrt(hd)
 
-    qh = to_heads(q, -l % blk_q)
-    kh = to_heads(k, -sk % blk_kv)
-    vh = to_heads(v, -sk % blk_kv)
+    with jax.named_scope(tracing.ATTN_RELAYOUT):
+        qh = to_heads(q, -l % blk_q)
+        kh = to_heads(k, -sk % blk_kv)
+        vh = to_heads(v, -sk % blk_kv)
     lp, skp = qh.shape[2], kh.shape[2]
     ctx_arr = jnp.asarray(ctx, jnp.int32).reshape((1,))
 
@@ -185,8 +188,10 @@ def terapipe_attention_fwd(q, k, v, ctx, *,
             jax.ShapeDtypeStruct((b, hq, lp, 1), jnp.float32),
         ],
         interpret=interpret,
+        name=tracing.FLASH_FWD,
     )(ctx_arr, qh, kh, vh)
-    return from_heads(out, l), lse[:, :, :l, 0]
+    with jax.named_scope(tracing.ATTN_RELAYOUT):
+        return from_heads(out, l), lse[:, :, :l, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("ctx_len", "blk_q", "blk_kv",

@@ -30,6 +30,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import tracing
+
 from .terapipe_attention import (DEFAULT_BLOCK_KV, DEFAULT_BLOCK_Q,
                                  align_block, from_heads, row_spec, to_heads)
 
@@ -148,9 +150,10 @@ def terapipe_attention_bwd(q, k, v, do, lse, delta, ctx, *,
     scale = 1.0 / math.sqrt(hd)
 
     l_pad, sk_pad = -l % blk_q, -sk % blk_kv
-    q, do = to_heads(q, l_pad), to_heads(do, l_pad)
-    k, v = to_heads(k, sk_pad), to_heads(v, sk_pad)
-    lse, delta = _rows(lse, l_pad), _rows(delta, l_pad)
+    with jax.named_scope(tracing.ATTN_RELAYOUT):
+        q, do = to_heads(q, l_pad), to_heads(do, l_pad)
+        k, v = to_heads(k, sk_pad), to_heads(v, sk_pad)
+        lse, delta = _rows(lse, l_pad), _rows(delta, l_pad)
     lp, skp = q.shape[2], k.shape[2]
     ctx_arr = jnp.asarray(ctx, jnp.int32).reshape((1,))
 
@@ -180,6 +183,7 @@ def terapipe_attention_bwd(q, k, v, do, lse, delta, ctx, *,
         grid_spec=dq_grid,
         out_shape=[jax.ShapeDtypeStruct((b, hq, lp, hd), q.dtype)],
         interpret=interpret,
+        name=tracing.FLASH_BWD_DQ,
     )(ctx_arr, q, k, v, do, lse, delta)
 
     # dK/dV sweep: kv blocks outer, (GQA group member, q block) inner — the
@@ -216,5 +220,7 @@ def terapipe_attention_bwd(q, k, v, do, lse, delta, ctx, *,
         out_shape=[jax.ShapeDtypeStruct((b, hkv, skp, hd), k.dtype),
                    jax.ShapeDtypeStruct((b, hkv, skp, hd), v.dtype)],
         interpret=interpret,
+        name=tracing.FLASH_BWD_DKV,
     )(ctx_arr, q, k, v, do, lse, delta)
-    return from_heads(dq, l), from_heads(dk, sk), from_heads(dv, sk)
+    with jax.named_scope(tracing.ATTN_RELAYOUT):
+        return from_heads(dq, l), from_heads(dk, sk), from_heads(dv, sk)

@@ -33,6 +33,7 @@ from types import SimpleNamespace
 import jax
 import numpy as np
 
+from repro import tracing
 from repro.checkpoint.manager import CheckpointManager
 from repro.compat import make_mesh, use_mesh
 from repro.configs import get_config
@@ -235,8 +236,10 @@ def setup(args: argparse.Namespace) -> SimpleNamespace:
 
     def train_step(params, opt_state, batch):
         loss, grads = vg_fn(params, batch)
-        updates, opt_state = opt.update(grads, opt_state, params)
-        return apply_updates(params, updates), opt_state, loss
+        with jax.named_scope(tracing.OPTIMIZER):
+            updates, opt_state = opt.update(grads, opt_state, params)
+            params = apply_updates(params, updates)
+        return params, opt_state, loss
 
     extra = None
     if cfg.family == "vlm":
@@ -289,11 +292,13 @@ def _supervise(args, params, opt_state, data, train_step):
     injected = False
     faulted = set()        # steps that faulted once
     step = start_step
-    t_last, tok_count = time.time(), 0
+    t_last, tok_count = time.perf_counter(), 0
     while step < args.steps:
         try:
-            batch = data.batch_at(step)
-            out = step_fn(params, opt_state, batch)
+            with jax.profiler.StepTraceAnnotation(tracing.STEP,
+                                                  step_num=step):
+                batch = data.batch_at(step)
+                out = step_fn(params, opt_state, batch)
             if args.simulate_failure_at == step and not injected:
                 # inject AFTER dispatch: with donation on, params/opt_state
                 # are now deleted — exactly the state a real mid-step fault
@@ -329,10 +334,10 @@ def _supervise(args, params, opt_state, data, train_step):
             continue
 
         if step % args.log_every == 0:
-            dt = time.time() - t_last
+            dt = time.perf_counter() - t_last
             print(f"step {step:5d} loss {float(loss):.4f} "
                   f"{tok_count/dt:,.0f} tok/s")
-            t_last, tok_count = time.time(), 0
+            t_last, tok_count = time.perf_counter(), 0
         if ckpt and step % args.checkpoint_every == 0:
             path = ckpt.save(step, {"params": params, "opt": opt_state,
                                     "step": step})

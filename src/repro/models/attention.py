@@ -20,6 +20,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
+
 from .common import (ModelConfig, apply_rope, attention_scores,
                      attention_scores_gqa, causal_mask, dense_init,
                      local_causal_mask, repeat_kv, rms_norm)
@@ -169,11 +171,14 @@ def attn_sliced(p, cfg: ModelConfig, x_slice: jnp.ndarray, kv_cache, ctx_len: in
     positions = (jnp.arange(l) + ctx_len)[None, :]
     q, k, v = _project_qkv(p, cfg, x_slice, positions, rope=cfg.rope_theta > 0)
     ck, cv = kv_cache
-    ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype), (0, ctx_len, 0, 0))
-    cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype), (0, ctx_len, 0, 0))
-    # keys for this slice: the prefix plus the slice itself (static size)
-    k_all = jax.lax.dynamic_slice(ck, (0, 0, 0, 0), (b, ctx_len + l, ck.shape[2], ck.shape[3]))
-    v_all = jax.lax.dynamic_slice(cv, (0, 0, 0, 0), (b, ctx_len + l, cv.shape[2], cv.shape[3]))
+    with jax.named_scope(tracing.CACHE):
+        ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype), (0, ctx_len, 0, 0))
+        cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype), (0, ctx_len, 0, 0))
+        # keys for this slice: the prefix plus the slice itself (static size)
+        k_all = jax.lax.dynamic_slice(ck, (0, 0, 0, 0),
+                                      (b, ctx_len + l, ck.shape[2], ck.shape[3]))
+        v_all = jax.lax.dynamic_slice(cv, (0, 0, 0, 0),
+                                      (b, ctx_len + l, cv.shape[2], cv.shape[3]))
     if cfg.use_kernel and window == 0:
         from repro.kernels import ops as kops
         out = kops.terapipe_attention(q, k_all.astype(q.dtype), v_all.astype(q.dtype),
@@ -211,8 +216,9 @@ def attn_sliced_dyn(p, cfg: ModelConfig, x_slice: jnp.ndarray, kv_cache, ctx,
     positions = jnp.arange(l)[None, :] + ctx
     q, k, v = _project_qkv(p, cfg, x_slice, positions, rope=cfg.rope_theta > 0)
     ck, cv = kv_cache
-    ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype), (0, ctx, 0, 0))
-    cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype), (0, ctx, 0, 0))
+    with jax.named_scope(tracing.CACHE):
+        ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype), (0, ctx, 0, 0))
+        cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype), (0, ctx, 0, 0))
     lmax = ck.shape[1]
     if cfg.use_kernel and window == 0:
         from repro.kernels import ops as kops

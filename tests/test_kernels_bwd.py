@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import tracing
 from repro.kernels import ops
 from repro.kernels.ref import terapipe_attention_ref
 
@@ -118,7 +119,8 @@ def test_fused_vjp_jaxpr_clean_via_analyzer():
     vmem = check_vmem(jaxpr)
     kernels = {f.data["kernel"] for f in vmem}
     assert not errors(vmem), vmem
-    assert {"_fwd_kernel", "_dq_kernel", "_dkv_kernel"} <= kernels, kernels
+    assert {tracing.FLASH_FWD, tracing.FLASH_BWD_DQ,
+            tracing.FLASH_BWD_DKV} <= kernels, kernels
 
 
 def test_custom_vjp_closure_is_cached():

@@ -8,15 +8,20 @@ at a time may load the TPU library, and every xdist worker imports this
 file.
 """
 import os
+import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import tracing
 from repro.kernels.decode_attention import decode_attention_kernel
 from repro.kernels.terapipe_attention import terapipe_attention_fwd
 from repro.kernels.terapipe_attention_bwd import terapipe_attention_bwd
+
+ROOT = Path(__file__).resolve().parents[1]
 
 # (name, B, l, Sk, Hq, Hkv, hd): a training slice of l tokens against the
 # whole Sk-token KV cache the executor hands the kernel (traced ctx)
@@ -85,3 +90,39 @@ def test_decode_compiles(one_chip, name, b, l, sk, hq, hkv, hd):
     kv_len = _sds((4,), jnp.int32, one_chip)
     _compile(lambda q, k, v, n: decode_attention_kernel(q, k, v, n),
              q, kv, kv, kv_len)
+
+
+def _reader_pattern(metric):
+    """The kernel pattern of a benchmark reader, as its file states it."""
+    text = (ROOT / "bench" / "metrics" / f"{metric}.py").read_text()
+    return re.search(r'^PATTERN = r"([^"]+)"', text, re.M).group(1)
+
+
+@pytest.mark.parametrize("name,b,l,sk,hq,hkv,hd", WIDTHS)
+def test_flash_kernel_names(one_chip, name, b, l, sk, hq, hkv, hd):
+    """The kernels' custom calls take their ``pallas_call`` names, which
+    the benchmark's readers find: the forward pattern only the forward,
+    the backward pattern both backward kernels (two per backward)."""
+    q = _sds((b, l, hq, hd), jnp.bfloat16, one_chip)
+    kv = _sds((b, sk, hkv, hd), jnp.bfloat16, one_chip)
+    rows = _sds((b, hq, l), jnp.float32, one_chip)
+    ctx = _sds((), jnp.int32, one_chip)
+
+    def fwd_bwd(q, k, v, do, lse, dl, c):
+        return (terapipe_attention_fwd(q, k, v, c),
+                terapipe_attention_bwd(q, k, v, do, lse, dl, c))
+    text = _compile(fwd_bwd, q, kv, kv, q, rows, rows, ctx).as_text()
+    calls = sorted(re.findall(r"^\s*(?:ROOT )?%(\S+) = .*custom_call_target="
+                              r'"tpu_custom_call"', text, re.M))
+    assert [re.sub(r"\.\d+$", "", c) for c in calls] == sorted(
+        [tracing.FLASH_BWD_DKV, tracing.FLASH_BWD_DQ, tracing.FLASH_FWD])
+    fwd = re.compile(_reader_pattern("flash_fwd_roofline"))
+    bwd = re.compile(_reader_pattern("flash_bwd_roofline"))
+    assert [c for c in calls if fwd.search(c)] == [
+        c for c in calls if c.startswith(tracing.FLASH_FWD + ".")]
+    assert len([c for c in calls if bwd.search(c)]) == 2
+    for kernel, metric in ((tracing.FLASH_BWD_DQ, "flash_dq_ms"),
+                           (tracing.FLASH_BWD_DKV, "flash_dkv_ms")):
+        one = re.compile(_reader_pattern(metric))
+        assert [c for c in calls if one.search(c)] == [
+            c for c in calls if c.startswith(kernel + ".")]
