@@ -13,11 +13,13 @@ path routes through here with the per-tick context offset.
 
 The custom_vjp wrapper is defined ONCE per static configuration (block
 sizes, interpret mode) at module scope via an lru_cache — a per-call closure
-would defeat jit caching and retrace on every call.
+would defeat jit caching and retrace on every call.  Blocks left as None are
+chosen per kernel from the shapes (``terapipe_attention.flash_plan``).
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -25,8 +27,7 @@ import jax.numpy as jnp
 from repro import tracing
 
 from .decode_attention import decode_attention_kernel
-from .terapipe_attention import (DEFAULT_BLOCK_KV, DEFAULT_BLOCK_Q,
-                                 terapipe_attention_fwd)
+from .terapipe_attention import terapipe_attention_fwd
 from .terapipe_attention_bwd import terapipe_attention_bwd
 
 
@@ -39,7 +40,8 @@ def _interpret() -> bool:
 
 
 @functools.lru_cache(maxsize=None)
-def _make_flash_attention(blk_q: int, blk_kv: int, interpret: bool):
+def _make_flash_attention(blk_q: Optional[int], blk_kv: Optional[int],
+                          interpret: bool):
     """custom_vjp-wrapped flash attention for one static kernel config.
 
     Module-level + cached: the returned function object is stable across
@@ -74,8 +76,8 @@ def _make_flash_attention(blk_q: int, blk_kv: int, interpret: bool):
 
 
 def terapipe_attention(q, k, v, *, ctx_len,
-                       blk_q: int = DEFAULT_BLOCK_Q,
-                       blk_kv: int = DEFAULT_BLOCK_KV) -> jnp.ndarray:
+                       blk_q: Optional[int] = None,
+                       blk_kv: Optional[int] = None) -> jnp.ndarray:
     """Flash attention of a query slice at context offset ``ctx_len``.
 
     q: (B, l, Hq, hd); k/v: (B, Sk, Hkv, hd) with Sk >= ctx_len + l.  GQA

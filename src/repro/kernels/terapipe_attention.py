@@ -19,13 +19,18 @@ accepts; lse is a (B, H, L, 1) column for the same reason.  Also:
   it may be a traced int32 — the lockstep pipeline executors run every stage
   at a different, data-dependent context offset (``attn_sliced_dyn``), and
   the causal-frontier block skip is computed from the prefetched scalar, so
-  blocks past ``ctx + l`` cost nothing even though the grid spans the whole
-  (static-size) KV cache.
+  blocks past ``ctx + l`` compute and copy nothing even though the grid
+  spans the whole (static-size) KV cache.
 * GQA is resolved in the K/V BlockSpec index map (kv head = q head // rep,
   as in decode_attention.py) — the repeated heads never exist in HBM.
-* Block sizes are rounded to MXU alignment instead of being clamped to a
-  ragged slice length (the DP planner emits e.g. l=96 slices); the position
-  masks make the pad exact.
+* Blocks come from the shapes (``flash_plan``): each kernel aims for its
+  own (blk_q, blk_kv) tile, tuned on a v5e, and takes the largest multiple
+  of 128 up to it that divides the 128-aligned extent.  A grid step costs a
+  fixed overhead (DMA issue and wait, pipeline bookkeeping) whether it
+  computes or the causal guard skips it; 1024-wide tiles cut the steps 64x
+  against 128-wide ones.  A ragged slice (the DP planner emits e.g. l=96)
+  pads to 128, never to a 96-wide block; the position masks make the pad
+  exact.  A 256-token slice keeps 256-row query blocks.
 
 Checked against kernels.ref in interpret mode on CPU; tests/test_tpu_compile.py
 compiles it for TPU v5e.
@@ -34,6 +39,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -42,10 +48,16 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro import tracing
 
-DEFAULT_BLOCK_Q = 128
-DEFAULT_BLOCK_KV = 128
 MXU_ALIGN = 128
 NEG_INF = float("-inf")
+
+# (blk_q, blk_kv) each kernel aims for; ``flash_plan`` fits them to the
+# shapes.  Tuned on a TPU v5e (PERF.md section 6, PR 15).
+TILE_TARGET = {
+    tracing.FLASH_FWD: (1024, 1024),
+    tracing.FLASH_BWD_DQ: (1024, 1024),
+    tracing.FLASH_BWD_DKV: (512, 1024),
+}
 
 
 def round_up(n: int, align: int) -> int:
@@ -57,6 +69,61 @@ def align_block(blk: int, n: int, align: int = MXU_ALIGN) -> int:
     extent, never clamped to an UNALIGNED extent (a ragged l=96 slice gets a
     full 128-wide MXU block + mask, not a 96-wide one)."""
     return min(blk, round_up(max(n, 1), align))
+
+
+def fit_block(target: int, n: int) -> int:
+    """The largest multiple of 128 up to ``target`` that divides the
+    128-aligned extent of ``n``: every kernel then pads ``n`` alike, to that
+    extent, whatever its block."""
+    ext = round_up(max(n, 1), MXU_ALIGN)
+    blk = align_block(target, n)
+    while ext % blk:
+        blk -= MXU_ALIGN
+    return blk
+
+
+class KernelPlan(NamedTuple):
+    blk_q: int
+    blk_kv: int
+    grid_steps: int                 # grid steps per call
+    compute_steps: Optional[int]    # those the causal guard lets compute
+
+
+def flash_plan(l: int, ctx: Optional[int], sk: int, b: int,
+               hq: int) -> Dict[str, KernelPlan]:
+    """Blocks of each flash kernel for a slice of ``l`` queries at context
+    ``ctx`` against ``sk`` keys with B x Hq query heads, and what they cost:
+    grid steps per call and the steps that compute (None for a traced
+    ``ctx``).  Every kernel's grid spans (B, Hq, query blocks, key blocks)
+    in some order (dK/dV's splits Hq into Hkv x the GQA group); a step
+    computes where its key block starts before the query block's causal
+    frontier.
+
+    The blocks depend on the shapes alone, so every caller (whole
+    sequences, the executor's slices at a traced ctx, serve prefill, the
+    cost model's tables) runs the same rule.  The 1024-wide tiles fit the
+    v5e's default scoped VMEM (tests/test_tpu_compile.py compiles them at
+    the benchmark's widths); 2048-wide ones do not."""
+    plan = {}
+    for name, (tq, tkv) in TILE_TARGET.items():
+        blk_q, blk_kv = fit_block(tq, l), fit_block(tkv, sk)
+        n_q = round_up(l, blk_q) // blk_q
+        n_kv = round_up(sk, blk_kv) // blk_kv
+        steps = None
+        if ctx is not None:
+            steps = b * hq * sum(
+                min(n_kv, -(-(ctx + min((iq + 1) * blk_q, l)) // blk_kv))
+                for iq in range(n_q))
+        plan[name] = KernelPlan(blk_q, blk_kv, b * hq * n_q * n_kv, steps)
+    return plan
+
+
+def kernel_blocks(plan: KernelPlan, blk_q: Optional[int],
+                  blk_kv: Optional[int], l: int, sk: int) -> Tuple[int, int]:
+    """The plan's blocks, or the caller's where it names them (rounded to
+    the aligned extent, as ``align_block`` does)."""
+    return (align_block(blk_q, l) if blk_q else plan.blk_q,
+            align_block(blk_kv, sk) if blk_kv else plan.blk_kv)
 
 
 def _fwd_kernel(ctx_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -129,30 +196,13 @@ def row_spec(blk: int, index_map):
     return pl.BlockSpec((None, None, blk, 1), index_map)
 
 
-@functools.partial(jax.jit, static_argnames=("blk_q", "blk_kv", "interpret"))
-def terapipe_attention_fwd(q, k, v, ctx, *,
-                           blk_q: int = DEFAULT_BLOCK_Q,
-                           blk_kv: int = DEFAULT_BLOCK_KV,
-                           interpret: bool = False):
-    """Fused forward: returns (out, lse).
-
-    q: (B, l, Hq, hd); k, v: (B, Sk, Hkv, hd) GQA-native, Sk >= ctx + l;
-    ctx: int32 scalar, may be TRACED (scalar-prefetch).  lse: (B, Hq, l) f32.
-    """
-    b, l, hq, hd = q.shape
-    sk, hkv = k.shape[1], k.shape[2]
-    assert k.shape == v.shape and hq % hkv == 0, (q.shape, k.shape)
-    rep = hq // hkv
-    blk_q = align_block(blk_q, l)
-    blk_kv = align_block(blk_kv, sk)
+def _fwd_call(ctx_arr, q, k, v, *, l: int, rep: int, blk_q: int,
+              blk_kv: int, interpret: bool):
+    """The forward kernel on head-major operands, padded to its blocks:
+    returns head-major (out, lse)."""
+    b, hq, lp, hd = q.shape
+    skp = k.shape[2]
     scale = 1.0 / math.sqrt(hd)
-
-    with jax.named_scope(tracing.ATTN_RELAYOUT):
-        qh = to_heads(q, -l % blk_q)
-        kh = to_heads(k, -sk % blk_kv)
-        vh = to_heads(v, -sk % blk_kv)
-    lp, skp = qh.shape[2], kh.shape[2]
-    ctx_arr = jnp.asarray(ctx, jnp.int32).reshape((1,))
 
     # GQA: kv-head block = q head // rep — no repeat in HBM.  The kv BLOCK
     # index is clamped to this q block's causal frontier (computed from the
@@ -179,17 +229,42 @@ def terapipe_attention_fwd(q, k, v, ctx, *,
             pltpu.VMEM((blk_q, hd), jnp.float32),   # output acc
         ],
     )
-    out, lse = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_fwd_kernel, l=l, blk_q=blk_q, blk_kv=blk_kv,
                           scale=scale),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, hq, lp, hd), q.dtype),
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct((b, hq, lp, 1), jnp.float32),
         ],
         interpret=interpret,
         name=tracing.FLASH_FWD,
-    )(ctx_arr, qh, kh, vh)
+    )(ctx_arr, q, k, v)
+
+
+@functools.partial(jax.jit, static_argnames=("blk_q", "blk_kv", "interpret"))
+def terapipe_attention_fwd(q, k, v, ctx, *,
+                           blk_q: Optional[int] = None,
+                           blk_kv: Optional[int] = None,
+                           interpret: bool = False):
+    """Fused forward: returns (out, lse).
+
+    q: (B, l, Hq, hd); k, v: (B, Sk, Hkv, hd) GQA-native, Sk >= ctx + l;
+    ctx: int32 scalar, may be TRACED (scalar-prefetch).  lse: (B, Hq, l) f32.
+    Blocks not given come from ``flash_plan``.
+    """
+    b, l, hq, hd = q.shape
+    sk, hkv = k.shape[1], k.shape[2]
+    assert k.shape == v.shape and hq % hkv == 0, (q.shape, k.shape)
+    plan = flash_plan(l, None, sk, b, hq)[tracing.FLASH_FWD]
+    blk_q, blk_kv = kernel_blocks(plan, blk_q, blk_kv, l, sk)
+    with jax.named_scope(tracing.ATTN_RELAYOUT):
+        qh = to_heads(q, -l % blk_q)
+        kh = to_heads(k, -sk % blk_kv)
+        vh = to_heads(v, -sk % blk_kv)
+    ctx_arr = jnp.asarray(ctx, jnp.int32).reshape((1,))
+    out, lse = _fwd_call(ctx_arr, qh, kh, vh, l=l, rep=hq // hkv,
+                         blk_q=blk_q, blk_kv=blk_kv, interpret=interpret)
     with jax.named_scope(tracing.ATTN_RELAYOUT):
         return from_heads(out, l), lse[:, :, :l, 0]
 
@@ -197,8 +272,8 @@ def terapipe_attention_fwd(q, k, v, ctx, *,
 @functools.partial(jax.jit, static_argnames=("ctx_len", "blk_q", "blk_kv",
                                              "interpret"))
 def terapipe_attention_kernel(q, k, v, *, ctx_len: int,
-                              blk_q: int = DEFAULT_BLOCK_Q,
-                              blk_kv: int = DEFAULT_BLOCK_KV,
+                              blk_q: Optional[int] = None,
+                              blk_kv: Optional[int] = None,
                               interpret: bool = False):
     """Back-compat forward-only entry (static ctx_len; heads may be GQA or
     already expanded).  New code should use ops.terapipe_attention."""

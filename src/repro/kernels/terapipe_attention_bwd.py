@@ -18,12 +18,15 @@ VMEM scratch across its innermost grid dimension:
 ``delta = rowsum(dO ∘ O)`` is linear in l and computed by the caller
 (kernels/ops.py) in plain jnp.  ``ctx`` is a scalar-prefetch operand exactly
 as in the forward — traced offsets from the pipeline executors drive the
-causal-frontier block skip.
+causal-frontier block skip.  Each kernel takes its own (blk_q, blk_kv) from
+``flash_plan`` (terapipe_attention.py): dK/dV aims for 512-row query blocks,
+which ran no slower on a v5e than dQ's 1024.
 """
 from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -32,8 +35,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro import tracing
 
-from .terapipe_attention import (DEFAULT_BLOCK_KV, DEFAULT_BLOCK_Q,
-                                 align_block, from_heads, row_spec, to_heads)
+from .terapipe_attention import (flash_plan, from_heads, kernel_blocks,
+                                 row_spec, to_heads)
 
 
 def _masked_p(q, k, lse, ctx, l, iq, ikv, blk_q, blk_kv, scale):
@@ -130,35 +133,15 @@ def _rows(a, pad):
     return jnp.pad(a, ((0, 0), (0, 0), (0, pad)))[..., None]
 
 
-@functools.partial(jax.jit, static_argnames=("blk_q", "blk_kv", "interpret"))
-def terapipe_attention_bwd(q, k, v, do, lse, delta, ctx, *,
-                           blk_q: int = DEFAULT_BLOCK_Q,
-                           blk_kv: int = DEFAULT_BLOCK_KV,
-                           interpret: bool = False):
-    """Fused backward: returns (dq, dk, dv).
-
-    q/do: (B, l, Hq, hd); k/v: (B, Sk, Hkv, hd) GQA-native; lse/delta:
-    (B, Hq, l) f32; ctx: int32 scalar, may be traced.  dk/dv come back in
-    the GQA-native (Hkv) layout — no repeated-head buffers anywhere.  The
-    kernels run head-major, as the forward does (``to_heads``).
-    """
-    b, l, hq, hd = q.shape
-    sk, hkv = k.shape[1], k.shape[2]
-    rep = hq // hkv
-    blk_q = align_block(blk_q, l)
-    blk_kv = align_block(blk_kv, sk)
+def _dq_call(ctx_arr, q, k, v, do, lse, delta, *, l: int, rep: int,
+            blk_q: int, blk_kv: int, interpret: bool):
+    """The dQ kernel on head-major operands, padded to its blocks."""
+    b, hq, lp, hd = q.shape
+    skp = k.shape[2]
     scale = 1.0 / math.sqrt(hd)
 
-    l_pad, sk_pad = -l % blk_q, -sk % blk_kv
-    with jax.named_scope(tracing.ATTN_RELAYOUT):
-        q, do = to_heads(q, l_pad), to_heads(do, l_pad)
-        k, v = to_heads(k, sk_pad), to_heads(v, sk_pad)
-        lse, delta = _rows(lse, l_pad), _rows(delta, l_pad)
-    lp, skp = q.shape[2], k.shape[2]
-    ctx_arr = jnp.asarray(ctx, jnp.int32).reshape((1,))
-
-    # kv / q block indices are clamped to the causal frontier (from the
-    # prefetched ctx): grid steps the pl.when guards skip revisit the same
+    # the kv block index is clamped to the causal frontier (from the
+    # prefetched ctx): grid steps the pl.when guard skips revisit the same
     # block and their HBM->VMEM copies are elided (see terapipe_attention).
     def _kv_index(bi, hi, qi, ki, ctx_ref):
         last = (ctx_ref[0] + jnp.minimum((qi + 1) * blk_q, l) - 1) // blk_kv
@@ -170,7 +153,7 @@ def terapipe_attention_bwd(q, k, v, do, lse, delta, ctx, *,
     q_spec = pl.BlockSpec((None, None, blk_q, hd), _q_index)
     kv_spec = pl.BlockSpec((None, None, blk_kv, hd), _kv_index)
     rspec = row_spec(blk_q, _q_index)
-    dq_grid = pltpu.PrefetchScalarGridSpec(
+    grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b, hq, lp // blk_q, skp // blk_kv),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, rspec, rspec],
@@ -180,15 +163,24 @@ def terapipe_attention_bwd(q, k, v, do, lse, delta, ctx, *,
     (dq,) = pl.pallas_call(
         functools.partial(_dq_kernel, l=l, blk_q=blk_q, blk_kv=blk_kv,
                           scale=scale),
-        grid_spec=dq_grid,
-        out_shape=[jax.ShapeDtypeStruct((b, hq, lp, hd), q.dtype)],
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)],
         interpret=interpret,
         name=tracing.FLASH_BWD_DQ,
     )(ctx_arr, q, k, v, do, lse, delta)
+    return dq
 
-    # dK/dV sweep: kv blocks outer, (GQA group member, q block) inner — the
-    # output block index is constant across the inner sweep, so the
-    # accumulators persist in scratch and each dK/dV block is written once.
+
+def _dkv_call(ctx_arr, q, k, v, do, lse, delta, *, l: int, rep: int,
+             blk_q: int, blk_kv: int, interpret: bool):
+    """The dK/dV kernel on head-major operands, padded to its blocks.
+
+    kv blocks outer, (GQA group member, q block) inner: the output block
+    index is constant across the inner sweep, so the accumulators persist
+    in scratch and each dK/dV block is written once."""
+    b, hq, lp, hd = q.shape
+    hkv, skp = k.shape[1], k.shape[2]
+    scale = 1.0 / math.sqrt(hd)
     n_q = lp // blk_q
 
     def _gq_block(qi, ki, ctx_ref):
@@ -205,22 +197,57 @@ def terapipe_attention_bwd(q, k, v, do, lse, delta, ctx, *,
     gkv_spec = pl.BlockSpec((None, None, blk_kv, hd),
                             lambda bi, hk, ki, r, qi, *_: (bi, hk, ki, 0))
     grow_spec = row_spec(blk_q, _gq_index)
-    dkv_grid = pltpu.PrefetchScalarGridSpec(
+    grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, hkv, skp // blk_kv, rep, lp // blk_q),
+        grid=(b, hkv, skp // blk_kv, rep, n_q),
         in_specs=[gq_spec, gkv_spec, gkv_spec, gq_spec, grow_spec, grow_spec],
         out_specs=[gkv_spec, gkv_spec],
         scratch_shapes=[pltpu.VMEM((blk_kv, hd), jnp.float32),
                         pltpu.VMEM((blk_kv, hd), jnp.float32)],
     )
-    dk, dv = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_dkv_kernel, l=l, blk_q=blk_q, blk_kv=blk_kv,
                           scale=scale, rep=rep),
-        grid_spec=dkv_grid,
-        out_shape=[jax.ShapeDtypeStruct((b, hkv, skp, hd), k.dtype),
-                   jax.ShapeDtypeStruct((b, hkv, skp, hd), v.dtype)],
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
         interpret=interpret,
         name=tracing.FLASH_BWD_DKV,
     )(ctx_arr, q, k, v, do, lse, delta)
+
+
+@functools.partial(jax.jit, static_argnames=("blk_q", "blk_kv", "interpret"))
+def terapipe_attention_bwd(q, k, v, do, lse, delta, ctx, *,
+                           blk_q: Optional[int] = None,
+                           blk_kv: Optional[int] = None,
+                           interpret: bool = False):
+    """Fused backward: returns (dq, dk, dv).
+
+    q/do: (B, l, Hq, hd); k/v: (B, Sk, Hkv, hd) GQA-native; lse/delta:
+    (B, Hq, l) f32; ctx: int32 scalar, may be traced.  dk/dv come back in
+    the GQA-native (Hkv) layout — no repeated-head buffers anywhere.  The
+    kernels run head-major, as the forward does (``to_heads``), each with
+    its own blocks: those given, else ``flash_plan``'s.  Both pad to the
+    least common multiple of their blocks.
+    """
+    b, l, hq, hd = q.shape
+    sk, hkv = k.shape[1], k.shape[2]
+    rep = hq // hkv
+    plan = flash_plan(l, None, sk, b, hq)
+    dq_blk = kernel_blocks(plan[tracing.FLASH_BWD_DQ], blk_q, blk_kv, l, sk)
+    dkv_blk = kernel_blocks(plan[tracing.FLASH_BWD_DKV], blk_q, blk_kv, l, sk)
+
+    l_pad = -l % math.lcm(dq_blk[0], dkv_blk[0])
+    sk_pad = -sk % math.lcm(dq_blk[1], dkv_blk[1])
+    with jax.named_scope(tracing.ATTN_RELAYOUT):
+        q, do = to_heads(q, l_pad), to_heads(do, l_pad)
+        k, v = to_heads(k, sk_pad), to_heads(v, sk_pad)
+        lse, delta = _rows(lse, l_pad), _rows(delta, l_pad)
+    ctx_arr = jnp.asarray(ctx, jnp.int32).reshape((1,))
+    args = (ctx_arr, q, k, v, do, lse, delta)
+    dq = _dq_call(*args, l=l, rep=rep, blk_q=dq_blk[0], blk_kv=dq_blk[1],
+                 interpret=interpret)
+    dk, dv = _dkv_call(*args, l=l, rep=rep, blk_q=dkv_blk[0],
+                      blk_kv=dkv_blk[1], interpret=interpret)
     with jax.named_scope(tracing.ATTN_RELAYOUT):
         return from_heads(dq, l), from_heads(dk, sk), from_heads(dv, sk)

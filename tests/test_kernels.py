@@ -6,9 +6,15 @@ import pytest
 
 from _hyp import given, settings, st   # hypothesis or skip-stub (tests/_hyp.py)
 
+from repro import tracing
+from repro.analysis.rules import check_vmem
 from repro.kernels import ops
 from repro.kernels.ref import terapipe_attention_ref
-from repro.kernels.terapipe_attention import terapipe_attention_kernel
+from repro.kernels.terapipe_attention import (MXU_ALIGN, flash_plan,
+                                              round_up,
+                                              terapipe_attention_fwd,
+                                              terapipe_attention_kernel)
+from repro.kernels.terapipe_attention_bwd import terapipe_attention_bwd
 
 pytestmark = pytest.mark.kernels
 
@@ -25,6 +31,7 @@ def _rand(shape, dtype, seed):
     (2, 100, 52, 3, 64),       # unaligned (padding path)
     (1, 256, 0, 2, 128),       # pure causal
     (1, 33, 7, 1, 32),         # tiny odd shapes
+    (1, 2048, 0, 2, 32),       # default blocks: 2 x 2 tiles of 1024
 ])
 def test_kernel_matches_oracle(b, l, ctx, h, hd, dtype, tol):
     q = _rand((b, l, h, hd), dtype, 0)
@@ -49,6 +56,71 @@ def test_kernel_property_shapes(l, ctx, hd, blk):
     ref = terapipe_attention_ref(q, k, v, ctx)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=3e-5, atol=3e-5)
+
+
+def _check_plan_blocks(plan, l, sk):
+    """128-aligned blocks that divide the aligned extents (so never exceed
+    them), and compute steps equal to the grid points the guard admits."""
+    for kernel, p in plan.items():
+        for blk, n in ((p.blk_q, l), (p.blk_kv, sk)):
+            assert blk % MXU_ALIGN == 0 and blk > 0, (kernel, p)
+            assert round_up(n, MXU_ALIGN) % blk == 0, (kernel, p, n)
+
+
+def _guarded_steps(l, ctx, sk, blk_q, blk_kv):
+    """Grid points (q block, kv block) whose kv block starts before the q
+    block's causal frontier — the kernels' pl.when guard, point by point."""
+    iq = np.arange(round_up(l, blk_q) // blk_q)[:, None]
+    ikv = np.arange(round_up(sk, blk_kv) // blk_kv)[None, :]
+    return int(np.sum(ikv * blk_kv < ctx + np.minimum((iq + 1) * blk_q, l)))
+
+
+@pytest.mark.parametrize("b,l,ctx,sk,hq,hkv,hd", [
+    (4, 2048, 0, 2048, 16, 8, 128),     # qwen3-0.6b.train-2k
+    (1, 8192, 0, 8192, 16, 8, 128),     # qwen3-0.6b.train-8k
+    (1, 256, 0, 2048, 16, 16, 128),     # the executor's 256-token slices
+    (1, 256, 1792, 2048, 16, 16, 128),
+    (1, 96, 1900, 2048, 4, 2, 32),      # ragged slice, stale cache tail
+])
+def test_flash_plan_matches_grids(b, l, ctx, sk, hq, hkv, hd):
+    """``flash_plan``'s blocks are legal, its grid steps are the product of
+    each kernel's real grid, its compute steps what the guard admits; a
+    256-token slice keeps 256-row query blocks."""
+    plan = flash_plan(l, ctx, sk, b, hq)
+    assert set(plan) == {tracing.FLASH_FWD, tracing.FLASH_BWD_DQ,
+                         tracing.FLASH_BWD_DKV}
+    _check_plan_blocks(plan, l, sk)
+    q = jax.ShapeDtypeStruct((b, l, hq, hd), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((b, sk, hkv, hd), jnp.bfloat16)
+    rows = jax.ShapeDtypeStruct((b, hq, l), jnp.float32)
+    jaxpr = jax.make_jaxpr(
+        lambda q, k, v, do, lse, dl, c: (
+            terapipe_attention_fwd(q, k, v, c, interpret=True),
+            terapipe_attention_bwd(q, k, v, do, lse, dl, c, interpret=True)))(
+        q, kv, kv, q, rows, rows, jnp.int32(ctx))
+    grids = {f.data["kernel"]: f.data["grid"] for f in check_vmem(jaxpr)}
+    for kernel, p in plan.items():
+        assert np.prod(grids[kernel]) == p.grid_steps, (kernel, grids, p)
+        assert p.compute_steps == b * hq * _guarded_steps(
+            l, ctx, sk, p.blk_q, p.blk_kv), (kernel, p)
+        if l == 256:
+            assert p.blk_q == 256, (kernel, p)
+
+
+@given(l=st.integers(1, 3000), ctx=st.integers(0, 3000),
+       tail=st.integers(0, 600))
+@settings(max_examples=50, deadline=None)
+def test_flash_plan_property(l, ctx, tail):
+    """Property: at any slice, context and stale tail the blocks are legal
+    and the compute steps are the guard's."""
+    sk = ctx + l + tail
+    plan = flash_plan(l, ctx, sk, 1, 1)
+    _check_plan_blocks(plan, l, sk)
+    for p in plan.values():
+        assert p.compute_steps == _guarded_steps(l, ctx, sk, p.blk_q,
+                                                 p.blk_kv), p
+        assert p.grid_steps == (round_up(l, p.blk_q) // p.blk_q
+                                * (round_up(sk, p.blk_kv) // p.blk_kv))
 
 
 def test_ops_wrapper_gqa_and_grad():

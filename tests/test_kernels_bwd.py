@@ -39,6 +39,7 @@ def _qkvg(b, l, ctx, hq, hkv, hd, dtype, sk_extra=0, seed=0):
     (1, 96, 160, 4, 1, 64),    # GQA 4x, ragged 96 (the DP planner shape)
     (2, 33, 7, 4, 1, 32),      # GQA 4x, tiny odd shapes
     (1, 100, 0, 4, 4, 64),     # ragged, pure causal
+    (1, 2048, 0, 2, 1, 32),    # default blocks: 2 x 2 tiles of 1024
 ])
 def test_fused_vjp_matches_reference(b, l, ctx, hq, hkv, hd, dtype, tol):
     q, k, v, g = _qkvg(b, l, ctx, hq, hkv, hd, dtype)
@@ -92,6 +93,32 @@ def test_traced_ctx_matches_static():
         for got, want in zip(grads_d, vjp_s(g)):
             np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                        rtol=2e-4, atol=2e-4)
+
+
+def test_default_blocks_ragged_traced_ctx_gqa_tail():
+    """Shape-chosen blocks (128-row query blocks, two 1024-wide key blocks)
+    at a ragged l=96 slice, GQA rep 2, a stale cache tail, and ctx traced:
+    the forward and the fused VJP match the reference."""
+    l, hq, hkv, hd, sk = 96, 4, 2, 32, 2048
+    q, k, v, g = _qkvg(1, l, sk - l, hq, hkv, hd, jnp.float32, seed=3)
+
+    @jax.jit
+    def dyn(q, k, v, c):
+        out, vjp = jax.vjp(
+            lambda q, k, v: ops.terapipe_attention(q, k, v, ctx_len=c),
+            q, k, v)
+        return out, vjp(g)
+
+    for c in (0, 1000, 1900):               # tail of 1952, 952 and 52 keys
+        out_d, grads_d = dyn(q, k, v, jnp.int32(c))
+        out_r, vjp_r = jax.vjp(
+            lambda q, k, v: terapipe_attention_ref(q, k, v, c), q, k, v)
+        np.testing.assert_allclose(np.asarray(out_d), np.asarray(out_r),
+                                   rtol=2e-4, atol=2e-4)
+        for got, want, name in zip(grads_d, vjp_r(g), ("dq", "dk", "dv")):
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       rtol=2e-4, atol=2e-4, err_msg=name)
+        assert float(jnp.abs(grads_d[1][:, c + l:]).max(initial=0.0)) == 0.0
 
 
 def test_fused_vjp_jaxpr_clean_via_analyzer():
