@@ -426,6 +426,9 @@ def _make_pipeline_body(p: _Plan):
         tab = np.concatenate([tab, pad])
     ticks = tab.shape[0]
     items_np, chunk_np, kcol_np = tab[..., 0], tab[..., 1], tab[..., 2]
+    tracing.count(tracing.TICKS, ticks)
+    tracing.count(tracing.RANKS, K)
+    tracing.count(tracing.IDLE_UNITS, np.sum(items_np < 0))
     splits = assign.splits_backward
     # per-(tick, rank) switch branch: 0 = idle, 1 = fwd, then the backward
     # arms — fused tables get one bwd branch (2); split tables get

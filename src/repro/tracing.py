@@ -7,6 +7,8 @@ named with ``pallas_call(name=...)``, which becomes the name of their
 custom call, and so of their event on the device.  The host spans are
 ``jax.profiler`` annotations.  Scopes and kernel names are compile-time
 metadata; the host spans cost nothing unless a profiler is recording.
+The counters are host numbers the program records while it builds a step
+(:func:`count`), for a reader in the same process (:data:`counters`).
 """
 
 # scopes
@@ -31,3 +33,17 @@ FLASH_BWD_DKV = "terapipe_attention_bwd-dkv"
 # host spans
 BATCH_AT = "data.batch_at"          # DataPipeline.batch_at
 STEP = "train"                      # StepTraceAnnotation, one per step
+
+# counters, recorded when the executor builds its tick loop
+TICKS = "tp.ticks"                  # ticks of one pass of the tick loop
+RANKS = "tp.ranks"                  # pipeline ranks of the tick table
+IDLE_UNITS = "tp.idle_units"        # (tick, rank) units the tick table
+                                    # marks idle (fill and drain); under
+                                    # fwd-only schedules they still compute
+
+counters: dict = {}
+
+
+def count(name: str, value) -> None:
+    """Record ``value`` under ``name``; the latest build wins."""
+    counters[name] = int(value)

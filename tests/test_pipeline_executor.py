@@ -247,6 +247,51 @@ def test_unified_executor_runs_every_schedule(K, n_layers):
     assert "ALL-SCHEDULES-EQUIV-OK" in out
 
 
+_PIPE4_GEOMETRY = """
+    import jax, jax.numpy as jnp
+    from repro.compat import make_mesh, use_mesh
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.core.pipeline import (make_terapipe_value_and_grad,
+                                     TeraPipeConfig)
+    cfg = get_config("gpt3-1b", smoke=True).replace(dtype=jnp.float32)
+    assert not cfg.tie_embeddings and cfg.n_layers % 4 == 0
+    model = build_model(cfg)
+    params, specs = model.init(jax.random.PRNGKey(0))
+    B, S = 4, 128
+    rng = jax.random.PRNGKey(11)
+    batch = {"tokens": jax.random.randint(rng, (B, S), 0, cfg.vocab_size),
+             "labels": jax.random.randint(rng, (B, S), 0, cfg.vocab_size)}
+    mesh = make_mesh((1, 4), ("data", "pipe"))
+    rel = lambda a, b: float(jnp.max(jnp.abs(a - b)) /
+                             (1e-6 + jnp.max(jnp.abs(b))))
+    lref, gref = jax.jit(jax.value_and_grad(model.loss))(params, batch)
+    tc = TeraPipeConfig(n_token_slices=8, n_microbatches=4,
+                        data_axes=("data",), cache_dtype=jnp.float32,
+                        schedule="contiguous", use_kernel=True)
+    with use_mesh(mesh):
+        vg, _ = make_terapipe_value_and_grad(model, specs, mesh, tc, S, B)
+        l, g = jax.jit(vg)(params, batch)
+    assert abs(float(l) - float(lref)) < 2e-5, (float(l), float(lref))
+    gerr = max(jax.tree.leaves(jax.tree.map(rel, g, gref)))
+    assert gerr < 2e-3, gerr
+    print("PIPE4-GEOMETRY-OK", float(l), gerr)
+"""
+
+
+def test_pipe4_cell_geometry_matches_model_loss():
+    """The gpt3-1b.pipe4-2k cell's geometry at the gpt3 smoke widths
+    (untied head, float32): the contiguous schedule on K=4 ranks, 4
+    microbatches of one row, 8 equal token slices, attention through the
+    flash kernels (interpret mode) at traced ``ctx``.  Loss and every grad
+    leaf match the non-pipelined ``model.loss`` within the tolerances of
+    ``test_unified_executor_runs_every_schedule``: everything is float32,
+    so the gaps are round-off from the order of summation across slices,
+    ticks and the kernels' online softmax."""
+    out = _run_subprocess(devices=4, code=_PIPE4_GEOMETRY)
+    assert "PIPE4-GEOMETRY-OK" in out
+
+
 def test_idle_ticks_leave_caches_bit_identical():
     """Satellite bugfix audit: cache mutation is gated on ``valid``, so
     fill/drain (and appended extra) idle ticks are exact cache no-ops.

@@ -30,6 +30,7 @@ WIDTHS = [
     ("gpt3-1b", 1, 512, 2048, 16, 16, 128),
     ("qwen3-0.6b", 4, 2048, 2048, 16, 8, 128),     # the benchmark's cells
     ("qwen3-0.6b", 1, 8192, 8192, 16, 8, 128),
+    ("gpt3-1b", 1, 256, 2048, 16, 16, 128),        # gpt3-1b.pipe4-2k's slice
 ]
 
 

@@ -74,3 +74,37 @@ def test_compiled_step_carries_phase_names(schedule):
         fwd = [o for o in names if holds(o, tracing.TICK_FWD)]
         assert any("transpose(" not in o for o in fwd)
         assert any("transpose(" in o for o in fwd)
+
+
+@pytest.mark.parametrize("K", [2, 4])
+def test_executor_counts_ticks_and_idle_units(K):
+    """Building the contiguous executor records ``tp.ticks``, ``tp.ranks``
+    and ``tp.idle_units`` as the schedule IR states them: ``n_ticks(D*M) =
+    D*M + K - 1`` ticks, K ranks, and the tick table's idle (tick, rank)
+    entries, K - 1 per rank.  Nothing runs: the loop is built on an abstract (1, K) mesh."""
+    import jax.numpy as jnp
+    from jax.sharding import AbstractMesh, AxisType
+    from repro.configs import get_config
+    from repro.core.pipeline import (TeraPipeConfig,
+                                     make_terapipe_value_and_grad)
+    from repro.core.schedules import get_schedule
+    from repro.models import build_model
+
+    cfg = get_config("gpt3-1b", smoke=True).replace(dtype=jnp.float32)
+    model = build_model(cfg)
+    specs = model.init(jax.random.PRNGKey(0))[1]
+    mesh = AbstractMesh((1, K), ("data", "pipe"),
+                        axis_types=(AxisType.Auto,) * 2)
+    D, M = 4, 8
+    make_terapipe_value_and_grad(
+        model, specs, mesh, TeraPipeConfig(n_token_slices=M,
+                                           n_microbatches=D), 64, D)
+    assign = get_schedule("contiguous", n_ranks=K, n_layers=cfg.n_layers,
+                          virtual_stages=1, n_microbatches=D)
+    tab = assign.tick_table(D * M)
+    idle = (tab[..., 0] < 0).sum(axis=0)
+    assert tracing.counters[tracing.TICKS] == assign.n_ticks(D * M) \
+        == D * M + K - 1
+    assert tracing.counters[tracing.RANKS] == K
+    assert list(idle) == [K - 1] * K
+    assert tracing.counters[tracing.IDLE_UNITS] == idle.sum()
